@@ -16,6 +16,8 @@ import argparse
 import sys
 import time
 
+from repro.launch.cache import enable_compilation_cache
+
 from . import (brownian, clipping, convergence, gradient_error, latent_sde,
                report, serving, solver_speed)
 
@@ -49,6 +51,7 @@ def main(argv=None) -> int:
         print(",".join(SUITES))
         return 0
     preset = "quick" if args.quick and args.preset == "full" else args.preset
+    enable_compilation_cache()
 
     names = [args.only] if args.only else list(SUITES)
     failures = 0

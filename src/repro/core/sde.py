@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
+from ..kernels.ops import vmap_rows
 from ..nn.core import tcat as _tcat  # the shared time-augmentation convention
 from .brownian import BrownianPath
 from .paths import LinearPathControl
@@ -511,9 +512,11 @@ def latent_sde_sample(params, cfg: LatentSDEConfig, key, batch: int):
 # contract is that **every trajectory row is a pure function of its own PRNG
 # key** (plus params), so the bucket-padding in launch/serve.py — padding an
 # off-size request batch up to the nearest compiled bucket — can never
-# perturb the rows a client actually asked for.  All solves dispatch through
-# :func:`_cfg_solve`, i.e. the unified ``repro.solve`` front-end: any
-# registered solver × noise type is servable.
+# perturb the rows a client actually asked for.  Rows are vmapped with
+# ``kernels.ops.vmap_rows``, which splits them over a data-parallel mesh.
+# All solves dispatch through :func:`_cfg_solve`, i.e. the unified
+# ``repro.solve`` front-end: any registered solver × noise type is
+# servable.
 
 
 def generator_sample_paths(params, cfg: NeuralSDEConfig, keys):
@@ -532,7 +535,7 @@ def generator_sample_paths(params, cfg: NeuralSDEConfig, keys):
                           x0, bm, cfg.num_steps, "general")
         return nn.linear(params["ell"], traj)
 
-    return jax.vmap(one, out_axes=1)(keys)
+    return vmap_rows(one, len(keys), out_axes=1)(keys)
 
 
 def generator_sample_terminal(params, cfg: NeuralSDEConfig, keys, rtol, atol,
@@ -570,7 +573,7 @@ def generator_sample_terminal(params, cfg: NeuralSDEConfig, keys, rtol, atol,
             dt0=cfg.t1 / cfg.num_steps, noise="general")
         return nn.linear(params["ell"], xT), stats.converged
 
-    return jax.vmap(one)(keys)
+    return vmap_rows(one, len(keys))(keys)
 
 
 def generator_initial_state(params, cfg: NeuralSDEConfig, keys):
@@ -623,8 +626,8 @@ def generator_rollout_chunk(params, cfg: NeuralSDEConfig, keys, x0, t_start,
                      noise="general")
         return nn.linear(params["ell"], traj), traj[-1]
 
-    return jax.vmap(one, in_axes=(0, 0, t_axis),
-                    out_axes=(1, 0))(keys, x0, t_start)
+    return vmap_rows(one, len(keys), in_axes=(0, 0, t_axis),
+                     out_axes=(1, 0))(keys, x0, t_start)
 
 
 def latent_sde_sample_paths(params, cfg: LatentSDEConfig, keys):
@@ -643,7 +646,7 @@ def latent_sde_sample_paths(params, cfg: LatentSDEConfig, keys):
                           params, x0, bm, cfg.num_steps, "diagonal")
         return nn.linear(params["ell"], traj)
 
-    return jax.vmap(one, out_axes=1)(keys)
+    return vmap_rows(one, len(keys), out_axes=1)(keys)
 
 
 def latent_sde_posterior_decode(params, cfg: LatentSDEConfig, keys, y_obs):
@@ -676,4 +679,5 @@ def latent_sde_posterior_decode(params, cfg: LatentSDEConfig, keys, y_obs):
                           x0, bm, cfg.num_steps, "diagonal")
         return nn.linear(params["ell"], traj)
 
-    return jax.vmap(one, in_axes=(0, 1), out_axes=1)(keys, y_obs)
+    return vmap_rows(one, len(keys), in_axes=(0, 1), out_axes=1)(
+        keys, y_obs)

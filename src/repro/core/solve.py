@@ -46,8 +46,8 @@ the backward's closed-form state reconstruction, AND the per-step local
 VJP all run fused — the hand-derived backward kernel pair is the
 derivative, registered through the reversible-adjoint ``custom_vjp``.
 Because the kernels take ``dt`` as a traced scalar operand this composes
-with ``adaptive=True``.  On non-TPU backends the kernels run in interpret
-mode automatically.
+with ``adaptive=True``.  On non-TPU backends the same steps run the
+kernels' jnp oracles (:mod:`repro.kernels.ref`).
 
 Batched multi-trajectory solving (:func:`solve_batched`) vmaps a batch of
 initial states against a batch of Brownian seeds — one fused XLA program

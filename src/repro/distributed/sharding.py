@@ -19,12 +19,45 @@ the Neural-SDE path run unsharded through identical code.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import os
+import re
+from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from .compat import ambient_mesh
+
+def ambient_mesh():
+    """The mesh activated by ``jax.set_mesh`` (abstract under tracing), or
+    ``None`` when unsharded."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
+    """``jax.make_mesh`` with Auto axes: the sharding constraints in this
+    module steer GSPMD, which Explicit axes (the JAX default) would not
+    take."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names))
+
+
+def force_host_device_count(n: int) -> None:
+    """Simulate ``n`` CPU devices (the ``--host-devices`` flag of
+    launch/train.py and launch/serve.py) by appending
+    ``--xla_force_host_platform_device_count`` to ``XLA_FLAGS``.
+
+    Must run before the XLA backend initialises — once it is up the flag
+    would be silently ignored, so this raises instead."""
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError("--host-devices must be processed before jax "
+                           "initialises; set XLA_FLAGS instead")
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={n}")
 
 
 def active_mesh_axes() -> Tuple[str, ...]:
@@ -101,16 +134,36 @@ def data_parallel_mesh(batch: Optional[int] = None):
     Both Neural-SDE training steps and the serving sampler are pure batch
     parallelism (DESIGN.md §4/§8/§9): parameters are tiny and replicated,
     only the sample batch shards.  Callers activate the mesh with
-    ``distributed.compat.set_mesh``.
+    ``jax.set_mesh``.
     """
-    from .compat import make_mesh
-
     n_dev = len(jax.devices())
     if n_dev <= 1:
         return None
     if batch is not None and batch % n_dev != 0:
         return None
     return make_mesh((n_dev,), ("data",))
+
+
+_COLLECTIVE = re.compile(r"= (.*?) (all-gather|all-reduce|all-to-all|"
+                         r"collective-permute|reduce-scatter)(?:-start)?\(")
+
+
+def batch_collectives(hlo: str, rows: int) -> list:
+    """The collectives in compiled HLO text whose result has a dim of
+    ``rows``: the path batch moved between devices, which a data-parallel
+    step should never do.  Every collective kind counts, since GSPMD may
+    gather a sharded operand with an all-reduce of a zero-padded buffer.
+    Returns ``[(kind, result shape), ...]``."""
+    found = []
+    for line in hlo.splitlines():
+        m = _COLLECTIVE.search(line)
+        if m is None:
+            continue
+        dims = [int(d) for group in re.findall(r"\[([\d,]*)\]", m.group(1))
+                for d in group.split(",") if d]
+        if rows in dims:
+            found.append((m.group(2), re.sub(r"\{[^}]*\}", "", m.group(1))))
+    return found
 
 
 # -----------------------------------------------------------------------------

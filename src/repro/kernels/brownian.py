@@ -7,13 +7,12 @@ bit-exact ``jax.random`` op sequence (:mod:`repro.kernels.prng`).
 
 Three kernels:
 
-* :func:`brownian_increment` — ``fold_in(key, n)`` + shaped normal draw
+* :func:`brownian_increment` — shaped normal draw under ``fold_in(key, n)``
   scaled by ``sqrt(dt)``; bitwise ``BrownianPath.increment(n, num_steps)``.
-* :func:`brownian_value` — the full Lévy-bridge descent of
-  ``BrownianPath.value(t)`` fused into one grid: in-kernel key chaining,
-  one batched midpoint draw, elementwise combine.  This is what lets the
-  adaptive driver pay a single launch per attempted step instead of
-  ``depth`` sequential draws.
+* :func:`brownian_value` — ``BrownianPath.value(t)``: the scalar descent
+  (per-level stds, directions, midpoint keys) runs in XLA and rides in as
+  SMEM operands; the kernel holds the full-shape work — one draw per level
+  and the elementwise combine.  One launch per attempted adaptive step.
 * :func:`rev_heun_phase1_gen` — Algorithm 1's first state update with the
   step's ``ΔW`` generated *inside the same kernel* (returns ``(ẑ_{n+1},
   ΔW)`` so phase 2 reuses the increment without re-deriving it).
@@ -21,16 +20,21 @@ Three kernels:
 Kernel contract
 ===============
 
-* The kernel bodies call the :mod:`repro.kernels.ref` oracles on loaded
-  values — kernel and oracle are the SAME traced op sequence, so bitwise
-  parity (tests/test_kernel_parity.py) holds by construction and the tests
-  pin that the Pallas lowering/interpreter preserves it.
-* Whole-array blocks: Brownian states here are small ``(batch, w_dim)``
-  tensors; each kernel runs as a single VMEM-resident block with scalar
-  operands (key halves, counter, times) in SMEM.  Shapes that overflow
-  VMEM should use the unfused oracle path (``use_kernel=False``).
-* ``interpret=True`` runs the body under the Pallas interpreter — the
-  CPU/CI validation path (DESIGN.md §5).
+* The kernel bodies call the :mod:`repro.kernels.ref` ``*_block`` oracles
+  on loaded values — kernel and oracle are the SAME traced op sequence, so
+  bitwise parity (tests/test_kernel_parity.py) holds by construction and
+  the tests pin that the Pallas lowering/interpreter preserves it.
+* Row grid: a draw of shape ``(..., cols)`` is laid out as ``(rows,
+  cols)`` and tiled by :func:`repro.kernels.reversible_heun_step.row_block`.
+  Each block derives its counters from its first global row — ``row0 +
+  program_id · block`` — so the bits depend neither on the tiling nor on
+  the data-parallel sharding: a shard passes its global row offset as
+  ``row0`` (:mod:`repro.kernels.ops` does this under a mesh).
+* Key folding (``fold_in(key, n)``, 20 scalar Threefry rounds) runs in XLA
+  before the launch; the kernel receives the folded key and ``sqrt(dt)``
+  as SMEM scalars.
+* ``interpret=True`` runs the body under the Pallas interpreter as one
+  block — the CPU validation path (DESIGN.md §5).
 """
 
 from __future__ import annotations
@@ -40,97 +44,107 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from . import ref
+from . import prng, ref
+from .reversible_heun_step import as_2d, row_block
 
-try:  # pltpu.SMEM exists only with the TPU plugin's pallas build
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover - CPU-only wheels
-    _SMEM = None
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _smem_spec():
-    if _SMEM is None:
-        return pl.BlockSpec(memory_space=None)
-    return pl.BlockSpec(memory_space=_SMEM)
+def _smem_row(*xs, dtype):
+    """Scalars as one ``(1, n)`` SMEM row.  The leading unit dim keeps the
+    block legal when ``vmap`` batches the operand (per-row keys in
+    serving): the batched ``(B, 1, n)`` array gets block ``(·, 1, n)``."""
+    return jnp.stack([jnp.asarray(x, dtype).reshape(()) for x in xs])[None]
 
 
-def _scalar_specs(n: int):
-    return [_smem_spec() for _ in range(n)]
+class _Row:
+    """``row[i]`` reads ``ref[0, i]`` — a ``(1, n)`` SMEM ref indexed like
+    the 1-D arrays :func:`repro.kernels.ref.bridge_block` takes."""
+
+    def __init__(self, ref_):
+        self.ref = ref_
+
+    def __getitem__(self, i):
+        return self.ref[0, i]
 
 
-def _increment_kernel(shape, dtype, k1_ref, k2_ref, n_ref, dt_ref, o_ref):
-    dw = ref.brownian_increment(k1_ref[0], k2_ref[0], n_ref[0], shape, dtype,
-                                dt_ref[0])
-    o_ref[...] = dw.reshape(o_ref.shape)
+def _row0(off_ref, br):
+    return off_ref[0, 0] + pl.program_id(0) * br
+
+
+def _increment_kernel(dtype, br, off_ref, k_ref, s_ref, o_ref):
+    o_ref[...] = ref.increment_block(k_ref[0, 0], k_ref[0, 1], o_ref.shape,
+                                     dtype, s_ref[0, 0], _row0(off_ref, br))
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "interpret"))
-def brownian_increment(k1, k2, n, shape, dtype, dt, interpret: bool = True):
+def brownian_increment(k1, k2, n, shape, dtype, dt, row0=0,
+                       interpret: bool = False):
     """Step-``n`` grid increment, generated in-kernel.
 
     ``k1, k2``: raw uint32 key halves; ``n``: step counter; ``dt``: the
-    grid spacing (scalar, may be traced).
+    grid spacing (scalar, may be traced); ``row0``: the global row of this
+    draw's first row (non-zero only for a shard of a larger draw).
     """
     dtype = jnp.dtype(dtype)
     shape = tuple(shape)
+    rows, cols = prng.as_rows(shape)
+    br = row_block(rows, cols, dtype, interpret)
+    f1, f2 = prng.fold_in(k1, k2, n)
     out = pl.pallas_call(
-        functools.partial(_increment_kernel, shape, dtype),
-        in_specs=_scalar_specs(4),
-        out_specs=pl.BlockSpec(shape, lambda: (0,) * len(shape)),
-        out_shape=jax.ShapeDtypeStruct(shape, dtype),
-        grid=(),
+        functools.partial(_increment_kernel, dtype, br),
+        grid=(pl.cdiv(rows, br),),
+        in_specs=[_SMEM] * 3,
+        out_specs=pl.BlockSpec((br, cols), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, cols), dtype),
         interpret=interpret,
-    )(jnp.asarray(k1, jnp.uint32).reshape(1),
-      jnp.asarray(k2, jnp.uint32).reshape(1),
-      jnp.asarray(n).reshape(1),
-      jnp.asarray(dt, dtype).reshape(1))
-    return out
+    )(_smem_row(row0, dtype=jnp.int32), _smem_row(f1, f2, dtype=jnp.uint32),
+      _smem_row(jnp.sqrt(jnp.asarray(dt, dtype)), dtype=dtype))
+    return out.reshape(shape)
 
 
-def _value_kernel(t0, t1, shape, dtype, depth, k1_ref, k2_ref, t_ref, o_ref):
-    w = ref.brownian_value(k1_ref[0], k2_ref[0], t_ref[0], t0, t1, shape,
-                           dtype, depth)
-    o_ref[...] = w.reshape(o_ref.shape)
+def _value_kernel(dtype, br, depth, off_ref, k_ref, f_ref, g_ref, o_ref):
+    o_ref[...] = ref.bridge_block(_Row(k_ref), _Row(f_ref), _Row(g_ref),
+                                  o_ref.shape, dtype, depth,
+                                  _row0(off_ref, br))
 
 
 @functools.partial(
     jax.jit, static_argnames=("t0", "t1", "shape", "dtype", "depth", "interpret"))
-def brownian_value(k1, k2, t, t0, t1, shape, dtype, depth: int = 24,
-                   interpret: bool = True):
-    """``W(t) − W(t0)`` with the whole bridge descent fused into one kernel."""
+def brownian_value(k1, k2, t, t0, t1, shape, dtype, depth: int = 24, row0=0,
+                   interpret: bool = False):
+    """``W(t) − W(t0)``: the bridge descent's scalars in XLA, its draws and
+    combine in one kernel."""
     dtype = jnp.dtype(dtype)
     shape = tuple(shape)
+    rows, cols = prng.as_rows(shape)
+    br = row_block(rows, cols, dtype, interpret)
+    keys, floats, gos = ref.bridge_descent(k1, k2, t, t0, t1, dtype, depth)
     out = pl.pallas_call(
-        functools.partial(_value_kernel, t0, t1, shape, dtype, depth),
-        in_specs=_scalar_specs(3),
-        out_specs=pl.BlockSpec(shape, lambda: (0,) * len(shape)),
-        out_shape=jax.ShapeDtypeStruct(shape, dtype),
-        grid=(),
+        functools.partial(_value_kernel, dtype, br, depth),
+        grid=(pl.cdiv(rows, br),),
+        in_specs=[_SMEM] * 4,
+        out_specs=pl.BlockSpec((br, cols), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, cols), dtype),
         interpret=interpret,
-    )(jnp.asarray(k1, jnp.uint32).reshape(1),
-      jnp.asarray(k2, jnp.uint32).reshape(1),
-      jnp.asarray(t, dtype).reshape(1))
-    return out
+    )(_smem_row(row0, dtype=jnp.int32), keys[None], floats[None], gos[None])
+    return out.reshape(shape)
 
 
-def _phase1_gen_kernel(shape, dtype, z_ref, zh_ref, mu_ref, sig_ref,
-                       k1_ref, k2_ref, n_ref, dt_grid_ref, dt_ref, sign_ref,
-                       zh1_ref, dw_ref):
-    dw = ref.brownian_increment(k1_ref[0], k2_ref[0], n_ref[0], shape, dtype,
-                                dt_grid_ref[0])
-    dw = dw.reshape(dw_ref.shape)
-    sign = sign_ref[0]
+def _phase1_gen_kernel(dtype, br, sign, off_ref, k_ref, s_ref,
+                       z_ref, zh_ref, mu_ref, sig_ref, zh1_ref, dw_ref):
+    dw = ref.increment_block(k_ref[0, 0], k_ref[0, 1], dw_ref.shape, dtype,
+                             s_ref[0, 0], _row0(off_ref, br))
     zh1_ref[...] = ref.rev_heun_phase1(z_ref[...], zh_ref[...], mu_ref[...],
-                                       sig_ref[...], dw, dt_ref[0], sign)
+                                       sig_ref[...], dw, s_ref[0, 1], sign)
     dw_ref[...] = dw
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("sign", "interpret"))
 def rev_heun_phase1_gen(z, zh, mu, sigma, k1, k2, n, dt_grid, dt,
-                        sign=1.0, interpret: bool = True):
+                        sign: float = 1.0, row0=0, interpret: bool = False):
     """Fused Algorithm-1 phase 1 + in-kernel ΔW generation.
 
     Returns ``(ẑ_{n+1}, ΔW_n)`` from one kernel launch: the increment is
@@ -143,20 +157,20 @@ def rev_heun_phase1_gen(z, zh, mu, sigma, k1, k2, n, dt_grid, dt,
     """
     dtype = z.dtype
     shape = tuple(z.shape)
-    spec = pl.BlockSpec(shape, lambda: (0,) * len(shape))
+    flat = [as_2d(a) for a in (z, zh, mu, sigma)]
+    rows, cols = flat[0].shape
+    br = row_block(rows, cols, dtype, interpret)
+    f1, f2 = prng.fold_in(k1, k2, n)
+    spec = pl.BlockSpec((br, cols), lambda i: (i, 0))
+    out = jax.ShapeDtypeStruct((rows, cols), dtype)
     zh1, dw = pl.pallas_call(
-        functools.partial(_phase1_gen_kernel, shape, dtype),
-        in_specs=[spec] * 4 + _scalar_specs(6),
+        functools.partial(_phase1_gen_kernel, dtype, br, sign),
+        grid=(pl.cdiv(rows, br),),
+        in_specs=[_SMEM] * 3 + [spec] * 4,
         out_specs=(spec, spec),
-        out_shape=(jax.ShapeDtypeStruct(shape, dtype),
-                   jax.ShapeDtypeStruct(shape, dtype)),
-        grid=(),
+        out_shape=(out, out),
         interpret=interpret,
-    )(z, zh, mu, sigma,
-      jnp.asarray(k1, jnp.uint32).reshape(1),
-      jnp.asarray(k2, jnp.uint32).reshape(1),
-      jnp.asarray(n).reshape(1),
-      jnp.asarray(dt_grid, dtype).reshape(1),
-      jnp.asarray(dt, dtype).reshape(1),
-      jnp.asarray(sign, dtype).reshape(1))
-    return zh1, dw
+    )(_smem_row(row0, dtype=jnp.int32), _smem_row(f1, f2, dtype=jnp.uint32),
+      _smem_row(jnp.sqrt(jnp.asarray(dt_grid, dtype)), dt, dtype=dtype),
+      *flat)
+    return zh1.reshape(shape), dw.reshape(shape)

@@ -8,21 +8,27 @@ in-kernel draws must be **bitwise identical** to what
 the forward/backward replay contract (DESIGN.md §10) is bitwise, so even
 1-ulp drift in the noise would break gradient exactness.
 
-This module is therefore a transcription of the exact op sequence of
-JAX's Threefry path (``jax._src.prng``, with the default
-``threefry_partitionable=False``), written only with primitives that are
-legal inside a Pallas kernel body (elementwise ``lax`` ops, ``iota``,
-bitcasts — no ``jax.random``, no key pytrees):
+This module transcribes the op sequence of JAX's Threefry path
+(``jax._src.prng``) under the installed default
+``jax_threefry_partitionable=True``, written only with primitives that are
+legal inside a Pallas TPU kernel body (elementwise ``lax`` ops, 2-D
+``broadcasted_iota``, bitcasts — no ``jax.random``, no key pytrees, no
+1-D iota, concatenate or reshape):
 
 * :func:`threefry2x32` — the 20-round hash (5 × 4 rounds, rotation
   schedule ``(13,15,26,6)/(17,29,16,24)``, key schedule
   ``k0, k1, k0^k1^0x1BD11BDA`` with round-index injections);
 * :func:`fold_in` — ``threefry2x32(key, seed_pair(n))``, matching
   ``jax.random.fold_in``'s counter scheme;
-* :func:`random_bits` — 32/64-bit streams over an ``iota`` counter with
-  JAX's odd-size padding and split-halves layout;
-* :func:`uniform` / :func:`normal` — the mantissa-shift bitcast and
-  ``sqrt(2)·erf_inv`` transform, op for op.
+* :func:`counters` — each element's counter is the ``(hi, lo)`` word pair
+  of its C-order flat index (``iota_2x32_shape``).  A draw of any shape is
+  laid out as ``(rows, cols)`` with ``cols = shape[-1]``; a block of rows
+  starting at ``row0`` derives its counters from ``row0`` alone, so the
+  bits do not depend on how the rows are tiled or sharded;
+* :func:`bits` — 32-bit draws are ``y1 ^ y2``, 64-bit draws
+  ``y1 << 32 | y2``, of the two hash lanes;
+* :func:`normal_block` / :func:`normal_like` — the mantissa-shift bitcast
+  and ``sqrt(2)·erf_inv`` transform of ``jax.random.normal``, op for op.
 
 tests/test_kernel_parity.py pins every function here bitwise against its
 ``jax.random`` counterpart across dtypes and shapes.
@@ -33,6 +39,7 @@ from __future__ import annotations
 import math
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -98,79 +105,80 @@ def fold_in(k1, k2, data) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return threefry2x32(k1, k2, hi, lo)
 
 
-def random_bits(k1, k2, bit_width: int, size: int) -> jnp.ndarray:
-    """Flat uint{32,64} stream of ``size`` draws — ``_threefry_random_bits``.
+def as_rows(shape) -> Tuple[int, int]:
+    """The ``(rows, cols)`` layout of a draw: ``cols`` is the last dim, a
+    1-D draw is one row, a scalar one element.  Raises for draws whose flat
+    index needs the high counter word (more than 2**32 elements)."""
+    shape = tuple(shape)
+    cols = shape[-1] if shape else 1
+    rows = math.prod(shape[:-1]) if shape else 1
+    if rows * cols >= 2 ** 32:
+        raise ValueError(f"draw of shape {shape} exceeds 2**32 elements")
+    return rows, cols
 
-    The counter layout mirrors JAX exactly: ``max_count =
-    ceil(bit_width·size/32)`` counters ``iota(uint32, max_count)``,
-    zero-padded to even length, split in half for the two hash lanes; for
-    64-bit output the two halves recombine as ``hi << 32 | lo``.
-    """
-    if bit_width not in (32, 64):
-        raise ValueError(f"bit_width must be 32 or 64, got {bit_width}")
-    max_count = -(-bit_width * size // 32)
-    odd = max_count % 2
-    half = (max_count + odd) // 2
-    counts = lax.iota(jnp.uint32, half)
-    x1 = counts
-    x2 = counts + np.uint32(half)
-    if odd:
-        # JAX pads the counter stream with one zero before splitting it in
-        # half, hashes, then drops the pad — lane 2's last counter is 0.
-        x2 = jnp.where(counts == np.uint32(half - 1), np.uint32(0), x2)
-    y1, y2 = threefry2x32(k1, k2, x1, x2)
-    bits = lax.concatenate([y1, y2[:half - odd]], 0)
+
+def counters(block_shape, row0=0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(hi, lo)`` counters of a ``(rows, cols)`` block whose first row is
+    global row ``row0`` (an int32 scalar, possibly traced): the flat index
+    ``(row0 + r) * cols + c`` — JAX's ``iota_2x32_shape`` restricted to the
+    block.  ``hi`` is zero because :func:`as_rows` bounds the draw size."""
+    cols = block_shape[-1]
+    r = lax.broadcasted_iota(jnp.int32, block_shape, 0) + row0
+    c = lax.broadcasted_iota(jnp.int32, block_shape, 1)
+    lo = (lax.convert_element_type(r, jnp.uint32) * np.uint32(cols)
+          + lax.convert_element_type(c, jnp.uint32))
+    return jnp.zeros(block_shape, jnp.uint32), lo
+
+
+def bits(k1, k2, hi, lo, bit_width: int) -> jnp.ndarray:
+    """uint{32,64} draws at the given counters — the partitionable
+    ``_threefry_random_bits``: ``y1 ^ y2`` for 32 bits, ``y1 << 32 | y2``
+    for 64."""
+    y1, y2 = threefry2x32(k1, k2, hi, lo)
+    if bit_width == 32:
+        return y1 ^ y2
     if bit_width == 64:
-        hi = lax.convert_element_type(bits[:size], jnp.uint64)
-        lo = lax.convert_element_type(bits[size:], jnp.uint64)
-        bits = lax.shift_left(hi, np.uint64(32)) | lo
-    return bits
+        return (lax.shift_left(lax.convert_element_type(y1, jnp.uint64),
+                               np.uint64(32))
+                | lax.convert_element_type(y2, jnp.uint64))
+    raise ValueError(f"bit_width must be 32 or 64, got {bit_width}")
 
 
-def uniform(k1, k2, size: int, dtype) -> jnp.ndarray:
-    """Flat uniforms on the *unit* transform of ``jax.random.uniform``
-    with ``minval=lo, maxval=hi`` applied by :func:`normal` — here the
-    raw ``bitcast(mantissa | 1.0) − 1`` stream in [0, 1)."""
+def normal_from_bits(b, dtype) -> jnp.ndarray:
+    """Standard normals from random bits — ``jax.random.normal``'s
+    transform: uniforms on ``[nextafter(-1, 0), 1)`` by the mantissa-shift
+    bitcast, then ``sqrt(2)·erf_inv``."""
     dtype = jnp.dtype(dtype)
     finfo = jnp.finfo(dtype)
     nbits, nmant = finfo.bits, finfo.nmant
     uint_dtype = jnp.uint32 if nbits == 32 else jnp.uint64
-    bits = random_bits(k1, k2, nbits, size)
     float_bits = lax.bitwise_or(
-        lax.shift_right_logical(bits, np.array(nbits - nmant, uint_dtype)),
+        lax.shift_right_logical(b, np.array(nbits - nmant, uint_dtype)),
         np.array(1.0, dtype).view(uint_dtype))
-    return lax.bitcast_convert_type(float_bits, dtype) - np.array(1.0, dtype)
-
-
-def uniform_range(k1, k2, size: int, dtype, minval, maxval) -> jnp.ndarray:
-    """``jax.random.uniform(key, (size,), dtype, minval, maxval)`` bitwise."""
-    dtype = jnp.dtype(dtype)
-    minval = np.array(minval, dtype)
-    maxval = np.array(maxval, dtype)
-    floats = uniform(k1, k2, size, dtype)
-    return lax.max(jnp.broadcast_to(minval, (size,)),
-                   floats * (maxval - minval) + minval)
-
-
-def normal(k1, k2, size: int, dtype) -> jnp.ndarray:
-    """Flat standard normals — bitwise ``jax.random.normal(key, (size,))``."""
-    dtype = jnp.dtype(dtype)
-    lo = np.nextafter(np.array(-1.0, dtype), np.array(0.0, dtype), dtype=dtype)
-    hi = np.array(1.0, dtype)
-    u = uniform_range(k1, k2, size, dtype, lo, hi)
+    floats = lax.bitcast_convert_type(float_bits, dtype) - np.array(1.0, dtype)
+    minval = np.nextafter(np.array(-1.0, dtype), np.array(0.0, dtype),
+                          dtype=dtype)
+    maxval = np.array(1.0, dtype)
+    u = lax.max(jnp.full(b.shape, minval, dtype),
+                floats * (maxval - minval) + minval)
     return lax.mul(np.array(np.sqrt(2), dtype), lax.erf_inv(u))
+
+
+def normal_block(k1, k2, block_shape, dtype, row0=0) -> jnp.ndarray:
+    """Rows ``row0 .. row0 + block_shape[0]`` of a ``(rows, cols)`` normal
+    draw — what a kernel's grid cell computes for its block."""
+    dtype = jax.dtypes.canonicalize_dtype(dtype)  # as jax.random does
+    hi, lo = counters(block_shape, row0)
+    return normal_from_bits(bits(k1, k2, hi, lo, dtype.itemsize * 8), dtype)
 
 
 def normal_like(k1, k2, shape: Tuple[int, ...], dtype) -> jnp.ndarray:
     """Shaped standard normals — bitwise ``jax.random.normal(key, shape)``."""
-    size = math.prod(shape)
-    return normal(k1, k2, size, dtype).reshape(shape)
+    return normal_block(k1, k2, as_rows(shape), dtype).reshape(tuple(shape))
 
 
 def key_data_pair(key) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Split a JAX PRNG key (typed or raw ``(2,) uint32``) into scalars."""
-    import jax
-
     if jnp.issubdtype(jnp.asarray(key).dtype, jax.dtypes.prng_key):
         key = jax.random.key_data(key)
     key = jnp.asarray(key)

@@ -73,6 +73,19 @@ def rev_heun_bwd_phase2(g_z1, ghat, dw, dt):
 # -----------------------------------------------------------------------------
 # counter-based Brownian generation (bitwise jax.random / BrownianPath)
 # -----------------------------------------------------------------------------
+#
+# Each oracle is split the way the kernel is: scalar work (key folding,
+# the bridge descent) runs in XLA, and a ``*_block`` function computes one
+# ``(rows, cols)`` block of the draw from those scalars and the block's
+# first global row ``row0``.  The kernel bodies call the same ``*_block``
+# functions on values loaded from their refs, so kernel and oracle are one
+# op sequence (tests/test_kernel_parity.py pins them bitwise).
+
+
+def increment_block(f1, f2, block_shape, dtype, scale, row0=0):
+    """Rows ``row0 ..`` of a grid increment: normals under the folded key
+    ``(f1, f2)`` times ``scale = sqrt(dt)``."""
+    return prng.normal_block(f1, f2, block_shape, dtype, row0) * scale
 
 
 def brownian_increment(k1, k2, n, shape, dtype, dt):
@@ -83,69 +96,88 @@ def brownian_increment(k1, k2, n, shape, dtype, dt):
     """
     dtype = jnp.dtype(dtype)
     f1, f2 = prng.fold_in(k1, k2, n)
-    z = prng.normal_like(f1, f2, tuple(shape), dtype)
-    return z * jnp.sqrt(jnp.asarray(dt, dtype))
+    scale = jnp.sqrt(jnp.asarray(dt, dtype))
+    return increment_block(f1, f2, prng.as_rows(shape), dtype,
+                           scale).reshape(tuple(shape))
 
 
-def brownian_value(k1, k2, t, t0, t1, shape, dtype, depth: int = 24):
-    """``W(t) − W(t0)`` by Lévy-bridge descent — bitwise
-    ``BrownianPath.value(t, depth)``.
+def bridge_descent(k1, k2, t, t0, t1, dtype, depth: int = 24):
+    """The scalar half of ``BrownianPath.value(t)``: everything in the
+    Lévy-bridge descent that depends on ``t`` alone.
 
-    Identical conditioning to ``BrownianPath._w`` but with the descent
-    *vectorised*: the interval sequence, per-level bridge stds and
-    per-level midpoint keys depend only on ``t`` (scalar work), so all
-    ``depth`` midpoint normals are drawn in ONE batched threefry+erf_inv
-    call instead of ``depth`` sequential full-shape draws — the op
-    sequence per element is unchanged, so every draw is bit-identical.
+    Returns ``(keys, floats, gos)``: ``keys`` is uint32 ``(2 + 2·depth,)``
+    — the root key, then each level's midpoint key; ``floats`` is
+    ``(2 + depth,)`` in ``dtype`` — ``sqrt(t1 - t0)``, the in-cell fraction
+    of ``t`` at the depth bound, then each level's bridge std; ``gos`` is
+    int32 ``(depth,)``, 1 where the descent went left.  These are the SMEM
+    operands of the ``brownian_value`` kernel.
     """
     dtype = jnp.dtype(dtype)
-    shape = tuple(shape)
     t = jnp.asarray(t, dtype)
-    span = t1 - t0
     r1, r2 = prng.fold_in(k1, k2, jnp.uint32(0xB0B))
-    w_t1 = prng.normal_like(r1, r2, shape, dtype) * jnp.sqrt(
-        jnp.asarray(span, dtype))
 
-    # -- scalar descent: intervals, stds, direction bits, midpoint keys
-    def scal_body(i, c):
-        a, b, c1, c2, stds, gos, km1s, km2s = c
+    def body(c, _):
+        a, b, c1, c2 = c
         m = 0.5 * (a + b)
         std = jnp.sqrt(jnp.asarray((b - m) * (m - a) / (b - a), dtype))
         go_left = t <= m
         f1, f2 = prng.fold_in(c1, c2, jnp.uint32(1))
         n1, n2 = prng.fold_in(
             c1, c2, jnp.where(go_left, jnp.uint32(2), jnp.uint32(3)))
-        stds = stds.at[i].set(std)
-        gos = gos.at[i].set(go_left)
-        km1s = km1s.at[i].set(f1)
-        km2s = km2s.at[i].set(f2)
         a2 = jnp.where(go_left, a, m)
         b2 = jnp.where(go_left, m, b)
-        return (a2, b2, n1, n2, stds, gos, km1s, km2s)
+        return (a2, b2, n1, n2), (std, go_left.astype(jnp.int32), f1, f2)
 
-    a0 = jnp.asarray(t0, dtype)
-    b0 = jnp.asarray(t1, dtype)
-    u0 = jnp.zeros((depth,), jnp.uint32)
-    a, b, _, _, stds, gos, km1s, km2s = lax.fori_loop(
-        0, depth, scal_body,
-        (a0, b0, r1, r2, jnp.zeros((depth,), dtype),
-         jnp.zeros((depth,), bool), u0, u0))
-
-    # -- ONE batched midpoint draw for all levels (the wall-clock win)
-    zms = jax.vmap(lambda u, v: prng.normal_like(u, v, shape, dtype))(
-        km1s, km2s)
-
-    # -- cheap sequential combine (elementwise FMAs + selects only)
-    def comb_body(i, c):
-        wa, wb = c
-        wm = 0.5 * (wa + wb) + stds[i] * zms[i]
-        return (jnp.where(gos[i], wa, wm), jnp.where(gos[i], wm, wb))
-
-    wa, wb = lax.fori_loop(0, depth, comb_body,
-                           (jnp.zeros(shape, dtype), w_t1))
+    (a, b, _, _), (stds, gos, km1s, km2s) = lax.scan(
+        body, (jnp.asarray(t0, dtype), jnp.asarray(t1, dtype), r1, r2),
+        None, length=depth)
     frac = jnp.clip((t - a) / jnp.maximum(b - a, jnp.finfo(dtype).tiny),
                     0.0, 1.0)
-    return wa + frac * (wb - wa)
+    keys = jnp.concatenate([jnp.stack([r1, r2]),
+                            jnp.stack([km1s, km2s], 1).reshape(-1)])
+    floats = jnp.concatenate([
+        jnp.stack([jnp.sqrt(jnp.asarray(t1 - t0, dtype)), frac]), stds])
+    return keys, floats, gos
+
+
+def bridge_block(keys, floats, gos, block_shape, dtype, depth: int,
+                 row0=0):
+    """Rows ``row0 ..`` of ``W(t) − W(t0)`` from :func:`bridge_descent`'s
+    scalars: the root draw, one midpoint draw per level, and the
+    elementwise combine.  ``keys``/``floats``/``gos`` may be arrays or
+    kernel refs — both index the same way."""
+    w_t1 = prng.normal_block(keys[0], keys[1], block_shape, dtype,
+                             row0) * floats[0]
+
+    def body(i, c):
+        wa, wb = c
+        zm = prng.normal_block(keys[2 + 2 * i], keys[3 + 2 * i], block_shape,
+                               dtype, row0)
+        wm = 0.5 * (wa + wb) + floats[2 + i] * zm
+        go_left = gos[i] != 0
+        return (jnp.where(go_left, wa, wm), jnp.where(go_left, wm, wb))
+
+    wa, wb = lax.fori_loop(0, depth, body,
+                           (jnp.zeros(block_shape, dtype), w_t1))
+    return wa + floats[1] * (wb - wa)
+
+
+def brownian_value(k1, k2, t, t0, t1, shape, dtype, depth: int = 24):
+    """``W(t) − W(t0)`` by Lévy-bridge descent — bitwise
+    ``BrownianPath.value(t, depth)``.
+
+    The interval sequence, per-level bridge stds and midpoint keys depend
+    only on ``t`` (:func:`bridge_descent`, scalar work); the full-shape
+    part is one draw per level and an elementwise combine
+    (:func:`bridge_block`).
+    """
+    dtype = jnp.dtype(dtype)
+    # the barrier hands the combine opaque scalars, as the kernel's SMEM
+    # operands are: XLA may not fold the descent into the combine's FMAs
+    keys, floats, gos = lax.optimization_barrier(
+        bridge_descent(k1, k2, t, t0, t1, dtype, depth))
+    return bridge_block(keys, floats, gos, prng.as_rows(shape), dtype,
+                        depth).reshape(tuple(shape))
 
 
 # -----------------------------------------------------------------------------

@@ -29,23 +29,22 @@ Kernel contract
   state shape.  General (matrix) noise needs an ``einsum`` per step and is
   served by the unfused path in :mod:`repro.core.solvers`.
 * **Shapes/tiling**: operands are flattened to ``(rows, cols)`` with
-  ``cols = shape[-1]`` (1-D states become ``(1, n)``).  Block sizes are the
-  largest divisor of each dim from the preference ladder
-  ``(256|512, 256, 128, 64, …, 1)``, so *any* shape is legal, but
-  performance wants ``cols`` a multiple of the 128-lane VPU width and
-  ``rows`` a multiple of 8 (f32) / 16 (bf16) sublanes.
+  ``cols = shape[-1]`` (1-D states become ``(1, n)``).  A block spans all
+  ``cols`` and a multiple of 8 (f32) / 16 (bf16) rows, over a
+  ``pl.cdiv`` row grid whose edge block Pallas masks (:func:`row_block`),
+  so every state shape compiles — including the Latent-SDE's
+  ``hidden + 1`` width.  Narrow states still occupy full 128-lane vregs.
 * **dt is a traced scalar operand**: ``dt`` rides in as a ``(1, 1)`` block
   broadcast to every grid cell, so one compiled kernel serves every step
   size — this is what lets the *adaptive* driver (traced, per-attempt
   ``dt``) use the fused path.  ``sign`` stays static (±1.0 is a branch of
   the algorithm, not data).
 * **Interpret mode**: ``interpret=True`` runs the kernel body under the
-  Pallas interpreter — required on CPU, and how CI validates the kernels
-  without a TPU (see tests/test_kernel_parity.py and tests/test_solve.py).
-  The solver hot loop does NOT pay this off-TPU: ``repro.core.solvers``
-  dispatches per the kernels/ops.py policy (compiled kernel on TPU, the
-  fused jnp oracle in :mod:`repro.kernels.ref` elsewhere) and only forces
-  the interpreter when a caller passes ``interpret=True`` explicitly.
+  Pallas interpreter — how the tests validate the kernels without a TPU
+  (tests/test_kernel_parity.py, tests/test_solve.py).  The default is the
+  compiled kernel.  Off-TPU the solver hot loop runs the jnp oracle in
+  :mod:`repro.kernels.ref` (the kernels/ops.py policy) and reaches the
+  interpreter only when a caller asks for it explicitly.
 * **Differentiability**: ``pallas_call`` still has no *automatic* VJP rule
   — but it no longer needs one: the backward phases above ARE the
   derivative, registered through the solver-level ``custom_vjp`` in
@@ -103,41 +102,57 @@ def _bwd_phase2_kernel(gz1_ref, ghat_ref, dw_ref, dt_ref,
     dsig_ref[...] = 0.5 * (g_z1 * dw) + ghat * dw
 
 
-def _tile(n: int, pref: int) -> int:
-    for t in (pref, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if t <= n and n % t == 0:
-            return t
-    return 1
+#: VMEM bytes one operand's row block may take (lane-padded), per buffer.
+#: Blocks are double-buffered, and the backward phase 2 moves 7 operands:
+#: 7 × 2 × 512 KiB = 7 MiB, inside the 16 MiB scoped-VMEM default.
+_BLOCK_BYTES = 512 * 1024
+
+
+def row_block(rows: int, cols: int, dtype, interpret: bool) -> int:
+    """Rows per block of a ``(rows, cols)`` operand.
+
+    The column block is always the whole last dim (Mosaic accepts a block
+    dim equal to the array dim, so odd state widths like ``hidden + 1``
+    compile).  The row block is a multiple of the dtype's sublane count
+    (8 for 32-bit, 16 for 16-bit) sized to :data:`_BLOCK_BYTES`, or all
+    rows when they fit in one block; callers grid over ``pl.cdiv(rows,
+    block)`` and Pallas masks the edge block.  Interpret mode always runs
+    one block: the interpreter's per-cell loop compiles each block as a
+    separate XLA subcomputation whose FMA contraction can differ from the
+    plain jnp oracle graph by ±1 ulp, and one block keeps the parity
+    contract of tests/test_kernel_parity.py bitwise.
+    """
+    if interpret:
+        return rows
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * 4 // itemsize
+    lanes = -(-cols // 128) * 128
+    cap = max(sublanes, _BLOCK_BYTES // (lanes * itemsize) // sublanes * sublanes)
+    return rows if rows <= cap else cap
+
+
+def as_2d(a):
+    """The kernels' ``(rows, cols)`` view: ``cols`` is the last dim, a 1-D
+    state is one row."""
+    return a.reshape(-1, a.shape[-1]) if a.ndim > 1 else a.reshape(1, -1)
 
 
 def _call_elementwise(kernel, args, scalars, interpret: bool, n_out: int = 1):
-    """Tiled elementwise pallas_call: tensor ``args`` share one block grid,
-    ``scalars`` ride along as (1, 1) blocks mapped to every grid cell.
-
-    Interpret mode runs the whole array as ONE block: the interpreter's
-    per-cell grid loop compiles each block as a separate XLA subcomputation,
-    and LLVM's FMA-contraction choices differ between that loop body and the
-    plain jnp oracle graph — observable as ±1-ulp drift at block boundaries.
-    A single block keeps interpret mode bit-identical to the oracle (the
-    parity contract tests/test_kernel_parity.py pins); compiled TPU mode
-    keeps the tile ladder for VMEM residency.
-    """
+    """Row-tiled elementwise pallas_call: tensor ``args`` share one block
+    grid (:func:`row_block`), ``scalars`` ride along as (1, 1) blocks
+    mapped to every grid cell."""
     x = args[0]
     orig_shape = x.shape
-    flat = [a.reshape(-1, orig_shape[-1]) if a.ndim > 1 else a.reshape(1, -1)
-            for a in args]
+    flat = [as_2d(a) for a in args]
     rows, cols = flat[0].shape
-    if interpret:
-        br, bc = rows, cols
-    else:
-        br, bc = _tile(rows, 256), _tile(cols, 512)
-    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
-    sspec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+    br = row_block(rows, cols, x.dtype, interpret)
+    spec = pl.BlockSpec((br, cols), lambda i: (i, 0))
+    sspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     svals = [jnp.asarray(s, x.dtype).reshape(1, 1) for s in scalars]
     shape = jax.ShapeDtypeStruct((rows, cols), x.dtype)
     out = pl.pallas_call(
         kernel,
-        grid=(rows // br, cols // bc),
+        grid=(pl.cdiv(rows, br),),
         in_specs=[spec] * len(flat) + [sspec] * len(svals),
         out_specs=spec if n_out == 1 else (spec,) * n_out,
         out_shape=shape if n_out == 1 else (shape,) * n_out,
@@ -150,7 +165,7 @@ def _call_elementwise(kernel, args, scalars, interpret: bool, n_out: int = 1):
 
 @functools.partial(jax.jit, static_argnames=("sign", "interpret"))
 def rev_heun_phase1(z, zh, mu, sigma, dw, dt, sign: float = 1.0,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """ẑ_{n+1} = 2z − ẑ + sign·(μΔt + σΔW) — fused, one HBM pass."""
     return _call_elementwise(
         functools.partial(_phase1_kernel, sign), (z, zh, mu, sigma, dw), (dt,),
@@ -159,7 +174,7 @@ def rev_heun_phase1(z, zh, mu, sigma, dw, dt, sign: float = 1.0,
 
 @functools.partial(jax.jit, static_argnames=("sign", "interpret"))
 def rev_heun_phase2(z, mu, mu1, sigma, sigma1, dw, dt, sign: float = 1.0,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """z_{n+1} = z + sign·(½(μ+μ′)Δt + ½(σ+σ′)ΔW) — fused, one HBM pass."""
     return _call_elementwise(
         functools.partial(_phase2_kernel, sign), (z, mu, mu1, sigma, sigma1, dw),
@@ -167,7 +182,7 @@ def rev_heun_phase2(z, mu, mu1, sigma, sigma1, dw, dt, sign: float = 1.0,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rev_heun_bwd_phase1(g_z1, g_mu1, g_sig1, dw, dt, interpret: bool = True):
+def rev_heun_bwd_phase1(g_z1, g_mu1, g_sig1, dw, dt, interpret: bool = False):
     """Backward pre-field phase: ``(c_mu1, c_sig1)`` seeds for the single
     vector-field VJP — ``c_mu1 = ḡ_mu1 + ½Δt·ḡ_z1``,
     ``c_sig1 = ḡ_sig1 + ½ΔW·ḡ_z1``."""
@@ -177,7 +192,7 @@ def rev_heun_bwd_phase1(g_z1, g_mu1, g_sig1, dw, dt, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rev_heun_bwd_phase2(g_z1, ghat, dw, dt, interpret: bool = True):
+def rev_heun_bwd_phase2(g_z1, ghat, dw, dt, interpret: bool = False):
     """Backward post-field phase: distribute the total ẑ₁ cotangent ``ĝ``
     onto the step-``n`` state — ``(d_z, d_zh, d_mu, d_sigma)``."""
     return _call_elementwise(

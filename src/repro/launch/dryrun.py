@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 # Multi-pod dry-run: AOT lower + compile of every (arch × shape × mesh) cell.
 #
 # Proves — without hardware — that the distribution config is coherent:
@@ -8,9 +5,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # memory fits.  The compiled artifact also feeds the roofline analysis
 # (benchmarks/roofline.py) via ``cost_analysis`` + the collective-bytes parse.
 #
-# The XLA_FLAGS assignment is the VERY FIRST statement — before ANY other
-# import — because jax locks the device count at first init.  Nothing else in
-# the repo sets it (smoke tests and benches see the real single device).
+# ``main`` asks for 512 placeholder CPU devices before jax initialises its
+# backend (jax locks the device count then); importing this module sets
+# nothing, so tests that import its helpers see the real devices.
 #
 # Usage::
 #
@@ -31,8 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs import ARCH_NAMES, SHAPES, cell_is_runnable, get_config
 from ..configs.base import ArchConfig, ShapeConfig
-from ..distributed.compat import set_mesh
-from ..distributed.sharding import param_pspecs
+from ..distributed.sharding import force_host_device_count, param_pspecs
 from ..models.counting import model_flops_per_token, param_count
 from ..optim.optimizers import OptState
 from .mesh import make_production_mesh
@@ -130,7 +126,7 @@ def opt_pspecs(params_pspecs):
 def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
                include_optimizer: bool = True):
     """Lower the step for one cell under ``mesh``.  Returns (lowered, kind)."""
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         specs = input_specs(cfg, shape)
         bspecs = batch_pspecs(specs, mesh)
         params_sds = abstract_params(cfg)
@@ -250,6 +246,7 @@ def main(argv: Optional[list] = None) -> int:
                     help="ArchConfig override key=value (hillclimb lever)")
     ap.add_argument("--variant", default="", help="label for override runs")
     args = ap.parse_args(argv)
+    force_host_device_count(512)
 
     overrides: Dict[str, Any] = {}
     for kv in args.set:

@@ -13,7 +13,7 @@ Mesh layout (TPU v5e pods):
 
 from __future__ import annotations
 
-from ..distributed.compat import make_mesh
+from ..distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -29,6 +29,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from .cache import enable_compilation_cache
 from ..serving import (  # noqa: F401  (re-exports: the PR 4-6 surface)
     Request,
     _adaptive_terminal_loop,
@@ -206,9 +207,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     if args.host_devices is not None:
-        from ..distributed.compat import force_host_device_count
+        from ..distributed.sharding import force_host_device_count
 
         force_host_device_count(args.host_devices)
+    enable_compilation_cache()
     if args.workload == "lm":
         return serve_lm(args.arch, args.batch, args.prompt_len, args.gen,
                         args.smoke, args.seed)

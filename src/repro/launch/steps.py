@@ -278,7 +278,8 @@ def make_latent_sde_step(cfg, opt_update, batch: int, seq_len: int,
         (grads,) = vjp(jnp.ones_like(loss))
         upd, opt_state = opt_update(grads, opt_state, params)
         params = optim.apply_updates(params, upd)
-        metrics = {"loss": loss, **parts}
+        metrics = {"loss": loss, "grad_norm": optim.global_norm(grads),
+                   **parts}
         return params, opt_state, metrics
 
     return step

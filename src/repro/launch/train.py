@@ -49,6 +49,7 @@ from .. import checkpoint as ckpt
 from ..configs import get_config, smoke_config
 from ..data.synthetic import token_batches
 from ..distributed.elastic import plan_mesh, surviving_devices
+from .cache import enable_compilation_cache
 from ..models import transformer as T
 from .steps import make_optimizer, make_train_step
 
@@ -196,13 +197,11 @@ def _sde_training_loop(tag: str, start: int, steps: int, batch: int, state,
     """
     import contextlib
 
-    from ..distributed.compat import set_mesh
-
     mesh = _data_parallel_mesh(batch, tag)
     if mesh is not None:
         print(f"[{tag}] data-parallel over {len(jax.devices())} devices",
               flush=True)
-    mesh_ctx = set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
+    mesh_ctx = jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
 
     def save(step, state):
         ckpt.save_checkpoint(ckpt_dir, step, state)
@@ -231,6 +230,29 @@ def _sde_training_loop(tag: str, start: int, steps: int, batch: int, state,
     return state, history
 
 
+def sde_gan_setup(batch: int, seed: int = 0, solver: str = "reversible_heun",
+                  use_pallas: bool = False, num_steps: int = 31,
+                  seq_len: int = 32, constraint: str = "clip",
+                  precision: str = "highest"):
+    """The SDE-GAN trainer's config, fresh state and jitted step:
+    ``(cfg, (params, g_state, d_state), step)`` with ``step(params, g_state,
+    d_state, key) -> (params, g_state, d_state, metrics)``."""
+    from ..core.sde import NeuralSDEConfig, discriminator_init, generator_init
+    from .steps import make_gan_optimizers, make_sde_gan_step
+
+    cfg = NeuralSDEConfig(
+        data_dim=1, hidden_dim=16, noise_dim=4, width=32, num_steps=num_steps,
+        solver=solver, exact_adjoint=solver == "reversible_heun",
+        use_pallas_kernels=use_pallas, precision=precision)
+    key = jax.random.PRNGKey(seed)
+    params = {"gen": generator_init(key, cfg),
+              "disc": discriminator_init(jax.random.fold_in(key, 1), cfg)}
+    (gi, gu), (di, du) = make_gan_optimizers(lr=1.0, constraint=constraint)
+    step = jax.jit(make_sde_gan_step(cfg, gu, du, batch, seq_len,
+                                     constraint=constraint))
+    return cfg, (params, gi(params["gen"]), di(params["disc"])), step
+
+
 def train_sde_gan(steps: int, batch: int, ckpt_dir: Optional[str] = None,
                   ckpt_every: int = 50, seed: int = 0, log_every: int = 10,
                   solver: str = "reversible_heun", use_pallas: bool = False,
@@ -247,27 +269,16 @@ def train_sde_gan(steps: int, batch: int, ckpt_dir: Optional[str] = None,
     optimiser chain, batch sharded over the data-parallel mesh.
     """
     from ..core.losses import signature_mmd
-    from ..core.sde import (NeuralSDEConfig, discriminator_init,
-                            generator_init, generator_sample)
+    from ..core.sde import generator_sample
     from ..data.synthetic import ou_process
-    from .steps import make_gan_optimizers, make_sde_gan_step
 
-    cfg = NeuralSDEConfig(
-        data_dim=1, hidden_dim=16, noise_dim=4, width=32, num_steps=num_steps,
-        solver=solver, exact_adjoint=solver == "reversible_heun",
-        use_pallas_kernels=use_pallas, precision=precision)
+    cfg, fresh, step_fn = sde_gan_setup(
+        batch, seed, solver=solver, use_pallas=use_pallas,
+        num_steps=num_steps, seq_len=seq_len, constraint=constraint,
+        precision=precision)
     key = jax.random.PRNGKey(seed)
-    params = {"gen": generator_init(key, cfg),
-              "disc": discriminator_init(jax.random.fold_in(key, 1), cfg)}
     data_key = jax.random.fold_in(key, 2)
-
-    (gi, gu), (di, du) = make_gan_optimizers(lr=1.0, constraint=constraint)
-    g_state, d_state = gi(params["gen"]), di(params["disc"])
-    step_fn = jax.jit(make_sde_gan_step(cfg, gu, du, batch, seq_len,
-                                        constraint=constraint))
-
-    state, start = _restore_or_fresh(ckpt_dir, (params, g_state, d_state),
-                                     "sde-gan")
+    state, start = _restore_or_fresh(ckpt_dir, fresh, "sde-gan")
 
     def gan_step(state, k):
         params, g_state, d_state = state
@@ -294,6 +305,31 @@ def train_sde_gan(steps: int, batch: int, ckpt_dir: Optional[str] = None,
     return params, mmds
 
 
+def latent_sde_setup(batch: int, seed: int = 0,
+                     solver: str = "reversible_heun", use_pallas: bool = False,
+                     num_steps: int = 23, seq_len: int = 24,
+                     adjoint: str = "exact", kl_weight: float = 0.1,
+                     lr: float = 1e-2, precision: str = "highest"):
+    """The Latent-SDE trainer's config, fresh state and jitted step:
+    ``(cfg, (params, opt_state), step)`` with ``step(params, opt_state,
+    key) -> (params, opt_state, metrics)``.  Grid alignment and the
+    solver × adjoint × fusion cell are validated here, before jit (see
+    :func:`repro.launch.steps.make_latent_sde_step`)."""
+    from ..core.sde import LatentSDEConfig, latent_sde_init
+    from .steps import make_latent_sde_optimizer, make_latent_sde_step
+
+    cfg = LatentSDEConfig(
+        data_dim=2, hidden_dim=16, context_dim=16, width=32,
+        num_steps=num_steps, solver=solver, kl_weight=kl_weight,
+        exact_adjoint=adjoint == "exact" and solver == "reversible_heun",
+        use_pallas_kernels=use_pallas, precision=precision)
+    params = latent_sde_init(jax.random.PRNGKey(seed), cfg)
+    oi, ou = make_latent_sde_optimizer(lr)
+    step = jax.jit(make_latent_sde_step(cfg, ou, batch, seq_len,
+                                        adjoint=adjoint))
+    return cfg, (params, oi(params)), step
+
+
 def train_latent_sde(steps: int, batch: int, ckpt_dir: Optional[str] = None,
                      ckpt_every: int = 50, seed: int = 0, log_every: int = 10,
                      solver: str = "reversible_heun", use_pallas: bool = False,
@@ -310,27 +346,12 @@ def train_latent_sde(steps: int, batch: int, ckpt_dir: Optional[str] = None,
     a state channel), one cotangent pull through the reversible-Heun exact
     adjoint (or the continuous-adjoint "backsolve" baseline).
     """
-    from ..core.sde import LatentSDEConfig, latent_sde_init
-    from .steps import make_latent_sde_optimizer, make_latent_sde_step
-
-    cfg = LatentSDEConfig(
-        data_dim=2, hidden_dim=16, context_dim=16, width=32,
-        num_steps=num_steps, solver=solver, kl_weight=kl_weight,
-        exact_adjoint=adjoint == "exact" and solver == "reversible_heun",
-        use_pallas_kernels=use_pallas, precision=precision)
-    key = jax.random.PRNGKey(seed)
-    params = latent_sde_init(key, cfg)
-    data_key = jax.random.fold_in(key, 2)
-
-    oi, ou = make_latent_sde_optimizer(lr)
-    opt_state = oi(params)
-    # eager validation (grid alignment, solver × adjoint × fusion) happens
-    # here, before jit — see make_latent_sde_step
-    step_fn = jax.jit(make_latent_sde_step(cfg, ou, batch, seq_len,
-                                           adjoint=adjoint))
-
-    state, start = _restore_or_fresh(ckpt_dir, (params, opt_state),
-                                     "latent-sde")
+    cfg, fresh, step_fn = latent_sde_setup(
+        batch, seed, solver=solver, use_pallas=use_pallas,
+        num_steps=num_steps, seq_len=seq_len, adjoint=adjoint,
+        kl_weight=kl_weight, lr=lr, precision=precision)
+    data_key = jax.random.fold_in(jax.random.PRNGKey(seed), 2)
+    state, start = _restore_or_fresh(ckpt_dir, fresh, "latent-sde")
 
     def vae_step(state, k):
         params, opt_state = state
@@ -423,9 +444,10 @@ def main(argv=None):
                          "backend initialises; must come before any jax use)")
     args = ap.parse_args(argv)
     if args.host_devices is not None:
-        from ..distributed.compat import force_host_device_count
+        from ..distributed.sharding import force_host_device_count
 
         force_host_device_count(args.host_devices)
+    enable_compilation_cache()
     if args.workload == "sde-gan":
         _, mmds = train_sde_gan(
             args.steps, args.batch, args.ckpt_dir, args.ckpt_every, args.seed,

@@ -6,6 +6,7 @@ from .optimizers import (  # noqa: F401
     chain,
     clip_by_global_norm,
     cosine_schedule,
+    global_norm,
     lipschitz_projection,
     swa_update,
 )

@@ -150,9 +150,14 @@ def lipschitz_projection(clip_fn=None):
     return init, update
 
 
+def global_norm(tree):
+    """L2 norm of every leaf of ``tree`` together, in float32."""
+    return jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2)
+                        for g in jax.tree.leaves(tree)))
+
+
 def clip_by_global_norm(grads, max_norm: float):
-    leaves = jax.tree.leaves(grads)
-    gnorm = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2) for g in leaves))
+    gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, max_norm / (gnorm + 1e-9))
     return jax.tree.map(lambda g: g * scale.astype(g.dtype), grads), gnorm
 

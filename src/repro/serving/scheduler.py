@@ -19,7 +19,10 @@ Joining mid-flight is bitwise-invisible: every row is a pure function of
 ``fold_in(PRNGKey(seed), j)``, chunk key ``fold_in(base, 1000 + c)`` —
 the identical keying the PR 4 stream loop used, so a request admitted
 into a half-full in-flight batch produces the trajectories it would have
-produced solo (tests/test_serving_scheduler.py pins this bitwise).
+produced solo (tests/test_serving_scheduler.py pins this bitwise).  Open
+defect: programs of different bucket sizes may round a row differently
+(DESIGN.md §11), so a request served alone through a smaller bucket can
+differ from its rows in a fuller batch by a few ulps.
 
 Adaptive *terminal* requests ride the same scheduler: they are coalesced
 per deadline class and each batch runs at the tolerance

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from .. import checkpoint as ckpt
-from ..distributed.compat import set_mesh
 from ..distributed.sharding import data_parallel_mesh
 from .registry import (LoadedModel, ModelRegistry, _init_params,
                        restore_for_serving)
@@ -197,7 +196,7 @@ def serve_sde(workload: str, ckpt_dir: Optional[str], smoke: bool,
         mesh = None
     buckets = serve_buckets(max_batch, n_dev if mesh is not None else 1)
     request_max = min(request_max, buckets[-1])
-    mesh_ctx = set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
+    mesh_ctx = jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
 
     stats: dict = {"workload": workload, "restored_step": step,
                    "buckets": buckets, "devices": n_dev}

@@ -38,10 +38,13 @@ def _source_blob() -> str:
 
 def test_referenced_markdown_files_exist():
     blob = _source_blob()
-    # uppercase markdown references resolve at the repo root or under docs/
-    missing = {name for name in set(re.findall(r"\b[A-Z][A-Z_]*\.md\b", blob))
+    # uppercase markdown references resolve at the repo root, under docs/,
+    # or at the repo-relative directory they are cited with
+    refs = set(re.findall(r"((?:[\w.-]+/)*)\b([A-Z][A-Z_]*\.md)\b", blob))
+    missing = {prefix + name for prefix, name in refs
                if not ((REPO / name).exists()
-                       or (REPO / "docs" / name).exists())}
+                       or (REPO / "docs" / name).exists()
+                       or (prefix and (REPO / prefix / name).exists()))}
     assert not missing, f"dangling doc references: {sorted(missing)}"
 
 

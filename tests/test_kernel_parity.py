@@ -354,3 +354,60 @@ def test_ops_forced_kernel_equals_oracle_path(key):
                                    jax.tree.leaves(oracle_out))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=f"ops dispatch leaf {i}")
+
+
+# -----------------------------------------------------------------------------
+# data-parallel dispatch: per-shard kernels draw the one-device bits
+# -----------------------------------------------------------------------------
+
+_SHARDED_SCRIPT = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.distributed.sharding import make_mesh
+from repro.kernels import ops
+
+key = jax.random.PRNGKey(3)
+z = jax.random.normal(key, (16, 17))
+
+def draws():
+    return (
+        ops.brownian_increment(key, 5, (16, 17), jnp.float32, 0.1,
+                               use_kernel=True),
+        ops.brownian_increment(key, 5, (8, 2, 3), jnp.float32, 0.1,
+                               use_kernel=True),
+        ops.brownian_value(key, 0.3, 0.0, 1.0, (16, 17), jnp.float32,
+                           use_kernel=True),
+        ops.rev_heun_phase1_gen(z, z, z, z, key, 5, 0.1, 0.1,
+                                use_kernel=True)[1],
+        ops.brownian_increment(key, 5, (5, 3), jnp.float32, 0.1,
+                               use_kernel=True))
+
+one = jax.jit(draws)()
+with jax.set_mesh(make_mesh((4,), ("data",))):
+    compiled = jax.jit(draws).lower().compile()
+    four = compiled()
+assert "all-gather" not in compiled.as_text()
+for a, b in zip(one, four):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+print("SHARDED-DRAWS-OK")
+"""
+
+
+def test_sharded_draws_equal_one_device_draws():
+    """Under a 4-device data-parallel mesh every Brownian kernel runs per
+    shard at its global row offset: the draws are bitwise the one-device
+    draws (rows split 4 ways, a 3-D path, and a batch that does not divide
+    and runs replicated), with no all-gather.  Runs in a child process:
+    the device count is fixed when JAX starts."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, PYTHONPATH=str(src), JAX_PLATFORMS="cpu",
+               REPRO_FORCE_PALLAS_INTERPRET="1",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0 and "SHARDED-DRAWS-OK" in proc.stdout, (
+        proc.stdout[-2000:] + proc.stderr[-4000:])

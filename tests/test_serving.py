@@ -171,9 +171,8 @@ def test_sde_serving_never_imports_transformer_stack():
 
 def _run_serve_cli(extra_args=(), extra_env=None):
     repo = Path(__file__).resolve().parents[1]
-    # pin XLA_FLAGS: importing repro.launch.dryrun anywhere in the pytest
-    # process (test_analysis does) exports a 512-device flag that these
-    # subprocesses would otherwise inherit
+    # pin XLA_FLAGS: the subprocess sees exactly the devices its own
+    # --host-devices flag asks for, whatever the caller's environment sets
     env = dict(os.environ, PYTHONPATH=str(repo / "src"), XLA_FLAGS="")
     env.update(extra_env or {})
     cmd = [sys.executable, "-m", "repro.launch.serve", "--smoke",

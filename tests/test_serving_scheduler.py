@@ -177,6 +177,28 @@ def test_mid_flight_admission_bitwise_equals_solo(key):
     np.testing.assert_array_equal(by_rid[1].samples, solo(late))
 
 
+def test_single_bucket_scheduler_bitwise_independent_of_load(key):
+    """With the bucket granularity equal to ``max_batch`` the ladder is one
+    bucket, and every batch, whatever its occupancy, runs that one
+    compiled program: requests served together are bitwise what each gets
+    served alone, and no other bucket compiles."""
+    reg = _registry(key)
+    reqs = [Request(rid=i, size=1 + i % 3, seed=50 + i) for i in range(5)]
+    one_bucket = {"max_batch": 8, "shard_base": 8, "chunks": 4,
+                  "collect": True}
+    sched = Scheduler(reg, **one_bucket)
+    assert sched.buckets == [8]
+    for r in reqs:
+        sched.submit(r)
+    by_rid = {r.rid: r for r in sched.run()}
+    for r in reqs:
+        solo = Scheduler(reg, **one_bucket)
+        solo.submit(Request(rid=99, size=r.size, seed=r.seed))
+        (res,) = solo.run()
+        np.testing.assert_array_equal(by_rid[r.rid].samples, res.samples)
+    assert {k[2] for k in reg.pool_keys()} == {8}
+
+
 def test_scheduler_rollout_bitwise_matches_stream_loop(key):
     """Independent oracle: the scheduler's chunked rollout reproduces the
     PR 4 stream loop bit for bit — same base key fold_in(PRNGKey(seed), j),

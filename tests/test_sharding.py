@@ -5,8 +5,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, smoke_config
-from repro.distributed.compat import abstract_mesh, make_mesh, set_mesh
-from repro.distributed.sharding import hint, param_pspecs
+from repro.distributed.sharding import hint, make_mesh, param_pspecs
 from repro.launch.specs import abstract_params, batch_pspecs, input_specs
 from repro.configs.base import SHAPES
 
@@ -29,7 +28,7 @@ def test_param_pspecs_under_mesh():
     mesh = make_mesh((1, 1), ("data", "model"))
     cfg = smoke_config("dbrx-132b")
     params = abstract_params(cfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         specs = param_pspecs(params, cfg.num_experts)
     flat = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     assert all(isinstance(s, P) for s in flat)
@@ -59,7 +58,9 @@ def test_input_specs_cover_all_cells():
 def test_batch_pspecs_divisibility():
     """No pspec may demand a finer split than the dim allows (the
     production-mesh sizes, via AbstractMesh — no devices needed)."""
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh(
+        (16, 16), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("jamba-v0.1-52b")
     specs = input_specs(cfg, SHAPES["long_500k"])
     ps = batch_pspecs(specs, mesh)
