@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import scopes
+
 
 def _normal_like(key: jax.Array, shape: Tuple[int, ...], dtype) -> jax.Array:
     return jax.random.normal(key, shape, dtype=dtype)
@@ -159,6 +161,7 @@ class BrownianPath:
                    levy_area=levy_area)
 
     # -- fixed-grid exact increments ----------------------------------------
+    @scopes.scoped(scopes.BROWNIAN)
     def increment(self, n: jax.Array, num_steps: int) -> jax.Array:
         """Exact increment of step ``n`` on the ``num_steps`` uniform grid.
 
@@ -211,6 +214,7 @@ class BrownianPath:
             return w, _h_from_wi(w, i, span, dtype)
         return self._w(t, depth)
 
+    @scopes.scoped(scopes.BROWNIAN)
     def _w(self, t, depth: int) -> jax.Array:
         """Sample W(t) by descending the virtual dyadic tree to ``depth``.
 
@@ -235,6 +239,7 @@ class BrownianPath:
         return ops.brownian_value(self.key, t, self.t0, self.t1, self.shape,
                                   self.dtype, depth=depth)
 
+    @scopes.scoped(scopes.BROWNIAN)
     def _wh(self, t, depth: int):
         """Joint ``(W(t) - W(t0), I(t))`` descent, where ``I(t) =
         ∫_{t0}^t (W_r - W_{t0}) dr`` is the running time-integral.
@@ -374,6 +379,7 @@ class DenseBrownianPath:
     def _dt_fine(self):
         return (self.t1 - self.t0) / self.fine_steps
 
+    @scopes.scoped(scopes.BROWNIAN)
     def increment(self, n: jax.Array, num_steps: int):
         r = self.fine_steps // num_steps
         assert r * num_steps == self.fine_steps, \
@@ -402,6 +408,7 @@ class DenseBrownianPath:
         return w, area / (r * dt_f) - 0.5 * w
 
     # -- arbitrary-interval queries (adaptive solvers) -----------------------
+    @scopes.scoped(scopes.BROWNIAN)
     def _w_at(self, t) -> jax.Array:
         """W(t) from the stored fine increments: exact at fine-grid nodes
         (prefix sums of ``w``), linearly interpolated inside a fine cell.
@@ -428,6 +435,7 @@ class DenseBrownianPath:
         inc = lax.dynamic_index_in_dim(self.w, i, 0, keepdims=False)
         return w_lo + frac * inc
 
+    @scopes.scoped(scopes.BROWNIAN)
     def _wi_at(self, t):
         """H-mode point query: ``(W(t) - W(t0), I(t))`` with ``I`` the
         running time-integral.  Exact at fine-grid nodes (prefix sums of

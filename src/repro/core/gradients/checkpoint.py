@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import scopes
 from ..brownian import stlevy_difference
 from ..solvers import RevHeunState, _tree_cast, reversible_heun_step
 from .base import GradientBackend, register_backend
@@ -112,6 +113,7 @@ def _chain(step, num_steps):
         carry, params, jnp.asarray(0, jnp.int32))
 
 
+@scopes.scoped(scopes.SOLVE)
 def checkpoint_solve(spec, drift, diffusion, params, z0, bm, t0, t1,
                      num_steps, noise):
     """Terminal value ``z_T``; AD through it follows the halving schedule.
@@ -139,6 +141,7 @@ def checkpoint_solve(spec, drift, diffusion, params, z0, bm, t0, t1,
     return _carry_z(spec, _chain(step, num_steps)(carry0, params))
 
 
+@scopes.scoped(scopes.SOLVE)
 def checkpoint_solve_adaptive(spec, drift, diffusion, params, z0, bm,
                               rtol, atol, t0, t1, max_steps, dt0, noise,
                               bridge_depth=None):

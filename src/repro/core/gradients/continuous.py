@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import scopes
 from ..brownian import BrownianPath
 from ..solvers import apply_diffusion
 from .base import GradientBackend, register_backend
@@ -50,6 +51,7 @@ def continuous_adjoint_solve(
     """
 
     @jax.custom_vjp
+    @scopes.scoped(scopes.SOLVE)
     def solve(params, z0):
         from ..solvers import sde_solve
 
@@ -62,6 +64,7 @@ def continuous_adjoint_solve(
         zT = solve(params, z0)
         return zT, (params, zT)
 
+    @scopes.scoped(scopes.ADJOINT)
     def bwd(residuals, g_zT):
         params, zT = residuals
         dt = (t1 - t0) / num_steps

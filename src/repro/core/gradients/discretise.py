@@ -10,6 +10,7 @@ or ``checkpoint`` for adaptive gradients).
 
 from __future__ import annotations
 
+from .. import scopes
 from ..solvers import sde_solve
 from .base import GradientBackend, register_backend
 
@@ -28,6 +29,7 @@ def _validate(spec, *, noise, save_trajectory, use_pallas, adaptive):
             "adjoint")
 
 
+@scopes.scoped(scopes.SOLVE)
 def _solve(spec, drift, diffusion, params, z0, bm, t0, t1, num_steps, *,
            noise, save_trajectory, use_pallas):
     return sde_solve(
@@ -39,6 +41,7 @@ def _solve(spec, drift, diffusion, params, z0, bm, t0, t1, num_steps, *,
         step_fn=None if spec.name == "reversible_heun" else spec.stepper)
 
 
+@scopes.scoped(scopes.SOLVE)
 def _solve_adaptive(spec, drift, diffusion, params, z0, bm, rtol, atol,
                     t0, t1, max_steps, dt0, *, noise, use_pallas,
                     bridge_depth):

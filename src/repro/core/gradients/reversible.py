@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import scopes
 from ..brownian import BrownianPath
 from ..solvers import (
     RevHeunState,
@@ -138,6 +139,7 @@ def reversible_heun_solve(
     return traj
 
 
+@scopes.scoped(scopes.SOLVE)
 def _forward(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
              use_pallas=False):
     dt = (t1 - t0) / num_steps
@@ -172,6 +174,7 @@ def _fwd_rule(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise, use_pa
     return traj, (params, final, bm)
 
 
+@scopes.scoped(scopes.ADJOINT)
 def _bwd_rule(drift, diffusion, t0, t1, num_steps, noise, use_pallas, residuals, g_traj):
     params, final, bm = residuals
     dt = (t1 - t0) / num_steps
@@ -268,6 +271,7 @@ def reversible_heun_solve_final(
     return final.z
 
 
+@scopes.scoped(scopes.SOLVE)
 def _fwd_rule_final(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise, use_pallas):
     dt = (t1 - t0) / num_steps
     dtype = z0.dtype
@@ -289,6 +293,7 @@ def _fwd_rule_final(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise, 
     return final.z, (params, final, bm)
 
 
+@scopes.scoped(scopes.ADJOINT)
 def _bwd_rule_final(drift, diffusion, t0, t1, num_steps, noise, use_pallas, residuals, g_zT):
     params, final, bm = residuals
     dt = (t1 - t0) / num_steps
@@ -390,6 +395,7 @@ def reversible_heun_solve_adaptive(
     return final.z, stats.converged
 
 
+@scopes.scoped(scopes.SOLVE)
 def _adaptive_forward(drift, diffusion, params, z0, bm, rtol, atol,
                       t0, t1, max_steps, dt0, noise, use_pallas=False,
                       bridge_depth=None):
@@ -417,6 +423,7 @@ def _fwd_rule_adaptive(drift, diffusion, params, z0, bm, rtol, atol,
         stats.num_accepted, jnp.asarray(rtol), jnp.asarray(atol))
 
 
+@scopes.scoped(scopes.ADJOINT)
 def _bwd_rule_adaptive(drift, diffusion, t0, t1, max_steps, dt0, noise,
                        use_pallas, bridge_depth, residuals, g_out):
     g_zT, _g_converged = g_out  # bool output: float0 cotangent, discarded

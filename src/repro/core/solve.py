@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import scopes
 from .brownian import BrownianPath, stlevy_difference
 from .gradients import (
     GRADIENT_BACKENDS,
@@ -487,6 +488,15 @@ def _check_bridge_depth(bm, bridge_depth) -> None:
             f"has a fixed resolution — drop bridge_depth")
 
 
+def _fields(precision, drift, diffusion):
+    """The fields as every backend evaluates them: in the precision
+    policy's compute dtype, each call inside the ``sde.field`` scope."""
+    field = scopes.scoped(scopes.FIELD)
+    drift, diffusion = resolve_precision(precision).wrap_fields(
+        drift, diffusion)
+    return field(drift), field(diffusion)
+
+
 def solve_adaptive(
     drift: Callable,
     diffusion: Callable,
@@ -518,13 +528,13 @@ def solve_adaptive(
     _check_levy_area(spec, bm)
     _check_adaptive_bm(bm)
     _check_bridge_depth(bm, bridge_depth)
-    drift, diffusion = resolve_precision(precision).wrap_fields(
-        drift, diffusion)
+    drift, diffusion = _fields(precision, drift, diffusion)
     if dt0 is None:
         dt0 = (t1 - t0) / 16
-    carry, stats = _adaptive_loop(spec, drift, diffusion, params, z0, bm,
-                                  t0, t1, rtol, atol, max_steps, dt0, noise,
-                                  bridge_depth=bridge_depth)
+    with scopes.scope(scopes.SOLVE):
+        carry, stats = _adaptive_loop(spec, drift, diffusion, params, z0, bm,
+                                      t0, t1, rtol, atol, max_steps, dt0,
+                                      noise, bridge_depth=bridge_depth)
     z = carry.z if spec.stepper is reversible_heun_step else carry
     return z, stats
 
@@ -669,8 +679,7 @@ def solve(
     # the precision policy wraps the fields BEFORE the backend sees them,
     # so adjoint replays/backsolves evaluate the same (wrapped) fields as
     # the forward; "highest" is the identity wrap
-    drift, diffusion = resolve_precision(precision).wrap_fields(
-        drift, diffusion)
+    drift, diffusion = _fields(precision, drift, diffusion)
 
     if adaptive:
         _check_adaptive_bm(bm)

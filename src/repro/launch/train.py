@@ -185,9 +185,14 @@ def _sde_training_loop(tag: str, start: int, steps: int, batch: int, state,
     periodic logging, step-granular atomic checkpoints.
 
     ``step_fn``: ``(state, key) -> (state, metrics)`` with ``state`` the
-    checkpointed pytree.  ``on_step(step, state, metrics, dt)`` handles
-    logging and returns a scalar to record in the returned history (or
-    ``None`` to record nothing for this step).
+    checkpointed pytree.  ``on_step(step, state, metrics)`` returns
+    ``(record, line)``: a scalar to record in the returned history (or
+    ``None``), and at a log point the line to print (or ``None``).  A log
+    point reads values back to the host, so the wall time since the
+    previous log point, taken after that read, covers the device work of
+    the steps in between: the line ends with it per step, and the
+    straggler monitor sees the same figure.  Each step runs inside a
+    ``jax.profiler.StepTraceAnnotation`` named ``train``.
 
     ``serving``: optional ``(workload, cfg, extract_params)`` handshake —
     every checkpoint save also writes the params-only serving bundle
@@ -212,17 +217,23 @@ def _sde_training_loop(tag: str, start: int, steps: int, batch: int, state,
 
     monitor = StragglerMonitor()
     history = []
+    t_logged, n_logged = time.perf_counter(), start
     with mesh_ctx:
         for step in range(start, steps):
-            t0 = time.time()
-            state, metrics = step_fn(state, jax.random.fold_in(data_key, step))
-            dt = time.time() - t0
-            if monitor.observe(dt):
-                print(f"[{tag}] straggler: step {step} took {dt:.2f}s",
-                      flush=True)
-            rec = on_step(step, state, metrics, dt)
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                state, metrics = step_fn(state,
+                                         jax.random.fold_in(data_key, step))
+            rec, line = on_step(step, state, metrics)
             if rec is not None:
                 history.append(rec)
+            if line is not None:
+                now = time.perf_counter()
+                dt = (now - t_logged) / (step + 1 - n_logged)
+                print(f"{line} {dt * 1e3:.1f}ms/step", flush=True)
+                if monitor.observe(dt):
+                    print(f"[{tag}] straggler: steps {n_logged}-{step} took "
+                          f"{dt:.2f}s a step", flush=True)
+                t_logged, n_logged = now, step + 1
             if ckpt_dir is not None and (step + 1) % ckpt_every == 0:
                 save(step + 1, state)
     if ckpt_dir is not None:
@@ -286,17 +297,15 @@ def train_sde_gan(steps: int, batch: int, ckpt_dir: Optional[str] = None,
                                                     d_state, k)
         return (params, g_state, d_state), metrics
 
-    def on_step(step, state, metrics, dt):
+    def on_step(step, state, metrics):
         if step % log_every != 0:
-            return None
+            return None, None
         y_real = ou_process(jax.random.fold_in(key, 777), 256, seq_len)
         fake = generator_sample(state[0]["gen"], cfg,
                                 jax.random.fold_in(key, 778), 256)
         mmd = float(signature_mmd(y_real, fake))
-        print(f"[sde-gan] step {step:5d} sig-MMD {mmd:.4f} "
-              f"W {float(metrics['wasserstein']):.4f} {dt*1e3:.0f}ms",
-              flush=True)
-        return mmd
+        return mmd, (f"[sde-gan] step {step:5d} sig-MMD {mmd:.4f} "
+                     f"W {float(metrics['wasserstein']):.4f}")
 
     (params, _, _), mmds = _sde_training_loop(
         "sde-gan", start, steps, batch, state, gan_step, data_key,
@@ -358,14 +367,13 @@ def train_latent_sde(steps: int, batch: int, ckpt_dir: Optional[str] = None,
         params, opt_state, metrics = step_fn(params, opt_state, k)
         return (params, opt_state), metrics
 
-    def on_step(step, state, metrics, dt):
+    def on_step(step, state, metrics):
         loss = float(metrics["loss"])
-        if step % log_every == 0:
-            print(f"[latent-sde] step {step:5d} -ELBO {loss:.4f} "
-                  f"recon {float(metrics['recon']):.4f} "
-                  f"kl_path {float(metrics['kl_path']):.4f} "
-                  f"{dt*1e3:.0f}ms", flush=True)
-        return loss
+        if step % log_every != 0:
+            return loss, None
+        return loss, (f"[latent-sde] step {step:5d} -ELBO {loss:.4f} "
+                      f"recon {float(metrics['recon']):.4f} "
+                      f"kl_path {float(metrics['kl_path']):.4f}")
 
     (params, _), losses = _sde_training_loop(
         "latent-sde", start, steps, batch, state, vae_step, data_key,

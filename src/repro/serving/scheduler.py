@@ -47,6 +47,7 @@ admission — is bitwise-invisible to the preempted trajectory
 
 from __future__ import annotations
 
+import functools
 import itertools
 import math
 import time
@@ -64,6 +65,28 @@ from .types import (DEADLINE_CLASSES, PAD_SEED, Request, ServeResult,
 #: Chunk-key fold offset — MUST stay equal to the stream loop's constant
 #: so scheduler rollouts are bitwise the PR 4 streamed rollouts.
 _CHUNK_FOLD = 1000
+
+
+def _span(name: str, counter: Optional[str] = None):
+    """Decorator for a ``Scheduler`` method: each call runs inside the
+    profiler span ``name`` (on the device trace's clock when a trace is
+    on), and with ``counter`` its host seconds, from one
+    ``time.perf_counter`` pair, add to ``self.counters[counter]``."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(self, *args, **kwargs):
+            t0 = time.perf_counter()
+            try:
+                with jax.profiler.TraceAnnotation(name):
+                    return fn(self, *args, **kwargs)
+            finally:
+                if counter is not None:
+                    self.counters[counter] += time.perf_counter() - t0
+
+        return call
+
+    return wrap
 
 
 def serve_buckets(max_batch: int, shard_base: int) -> list:
@@ -206,9 +229,12 @@ class Scheduler:
         self.preempt = preempt
         self.quota = quota
         #: Observable scheduling counters (benchmarks charge virtual time
-        #: per executed batch; tests assert preemption really engaged).
+        #: per executed batch; tests assert preemption really engaged), and
+        #: the host seconds spent admitting, advancing and serving terminal
+        #: batches, device waits included.
         self.counters = {"chunk_batches": 0, "terminal_batches": 0,
-                         "preempted_rows": 0, "resumed_rows": 0}
+                         "preempted_rows": 0, "resumed_rows": 0,
+                         "admit_s": 0.0, "advance_s": 0.0, "terminal_s": 0.0}
         self._clock = clock
         self._t0 = clock()
         self._seq = itertools.count()
@@ -354,6 +380,7 @@ class Scheduler:
 
     # -- the iteration ------------------------------------------------------
 
+    @_span("serve.step")
     def step(self) -> List[ServeResult]:
         """One scheduler iteration: per lane, serve at most one terminal
         batch, admit pending rollouts into free slots, and advance every
@@ -430,6 +457,7 @@ class Scheduler:
             results += self.step()
         return results
 
+    @_span("serve.admit", "admit_s")
     def _admit(self, lane: _Lane) -> None:
         if self.mode == "fifo" and (lane.active or lane.paused):
             return  # baseline: the in-flight batch drains before coalescing
@@ -460,6 +488,7 @@ class Scheduler:
                 lane.active.append(_Row(flight, j, x0[i]))
                 i += 1
 
+    @_span("serve.advance", "advance_s")
     def _advance(self, lane: _Lane) -> List[ServeResult]:
         if not lane.active:
             return []
@@ -504,6 +533,7 @@ class Scheduler:
         lane.active = still_active
         return results
 
+    @_span("serve.finish")
     def _finish(self, flight: _InFlight) -> ServeResult:
         req = flight.request
         samples = None
@@ -519,6 +549,7 @@ class Scheduler:
 
     # -- adaptive terminal batches (SLO-routed) -----------------------------
 
+    @_span("serve.terminal", "terminal_s")
     def _step_terminal(self, lane: _Lane,
                        defer_relaxed: bool = False) -> List[ServeResult]:
         if not lane.pending_term:

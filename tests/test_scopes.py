@@ -1,0 +1,96 @@
+"""The solve stack's named scopes (``repro.core.scopes``) in the compiled
+SDE-GAN training step, at the size the benchmark's CPU tests cut its
+``ou_gan`` cell to, with the exact adjoint as the cell runs it.
+
+A scope is HLO metadata: the step must compile to the same program with
+and without the scopes.  XLA names an instruction after the last component
+of its ``op_name``, so the comparison strips the metadata and the
+source-location tables and renames instructions and computations in order
+of appearance."""
+
+import contextlib
+import json
+import re
+
+import jax
+import pytest
+
+from bench.models.sde_gan import program_config
+from bench.tests import tiny
+from repro.core import scopes
+from repro.core.sde import discriminator_init, generator_init
+from repro.launch.steps import make_gan_optimizers, make_sde_gan_step
+
+SCOPES = (scopes.SOLVE, scopes.ADJOINT, scopes.BROWNIAN, scopes.FIELD)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_METADATA = re.compile(r',?\s*(?<![\w])metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A fresh compile for every call: the persistent cache (which another
+    test in the process may have turned on) keys programs without their
+    metadata, so it would hand the second compile the first one's
+    executable, scopes and all."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        compilation_cache.reset_cache()
+
+
+def _compiled_step() -> str:
+    config = json.loads((tiny.REPO / "bench" / "configs" / "ou_gan.json")
+                        .read_text())
+    config["model"].update(tiny.CONFIGS["ou_gan"])
+    cell = tiny.CELLS["ou_gan.train_b1024"]
+    cfg = program_config(config, "highest")
+    assert cfg.exact_adjoint
+    (g_init, g_update), (d_init, d_update) = make_gan_optimizers(
+        lr=1.0, constraint="clip")
+    step = jax.jit(make_sde_gan_step(cfg, g_update, d_update, cell["batch"],
+                                     cell["seq_len"], constraint="clip"))
+    key = jax.random.PRNGKey(0)
+    params = {"gen": generator_init(key, cfg),
+              "disc": discriminator_init(jax.random.fold_in(key, 1), cfg)}
+    return step.lower(params, g_init(params["gen"]), d_init(params["disc"]),
+                      jax.random.PRNGKey(1)).compile().as_text()
+
+
+def _program(hlo: str) -> str:
+    """The compiled module with metadata, the source-location tables and
+    instruction names taken out."""
+    lines = hlo.splitlines()
+    body = next(i for i, ln in enumerate(lines)
+                if ln.startswith(("%", "ENTRY")))
+    text = _METADATA.sub("", "\n".join(lines[:1] + lines[body:]))
+    names: dict = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%v{len(names)}"),
+                  text)
+
+
+def test_compiled_step_names_every_scope(no_compile_cache):
+    names = _OP_NAME.findall(_compiled_step())
+    for scope in SCOPES:
+        assert any(scope in n.split("/") or f"({scope})" in n for n in names), (
+            scope)
+    adjoint = [n for n in names if scopes.ADJOINT in n]
+    assert adjoint
+    for n in adjoint:
+        # the backward rules run only inside autodiff's transpose
+        assert "transpose(" in n[:n.index(scopes.ADJOINT)], n
+
+
+def test_scopes_change_no_program(monkeypatch, no_compile_cache):
+    scoped = _compiled_step()
+    monkeypatch.setattr(scopes, "scope", contextlib.nullcontext)
+    jax.clear_caches()
+    plain = _compiled_step()
+    assert not any(s in n for n in _OP_NAME.findall(plain) for s in SCOPES)
+    assert _program(scoped) == _program(plain)

@@ -144,6 +144,30 @@ def test_scheduler_routes_terminal_batches_by_deadline_class(key):
         assert r.num_converged == r.size  # default budget is ample here
 
 
+def test_scheduler_host_second_counters(key):
+    """``admit_s``, ``advance_s`` and ``terminal_s`` sum host seconds
+    inside the scheduler's own phases: after a run with rollouts and
+    terminal requests each is positive, and together they fit inside the
+    wall time around the ``step`` calls."""
+    import time
+
+    sched = Scheduler(_registry(key), max_batch=4, chunks=2)
+    sched.warm("default", kinds=("init", "chunk", "terminal"))
+    for i in range(3):
+        sched.submit(Request(rid=i, size=1 + i, seed=20 + i))
+    sched.submit(Request(rid=9, size=1, seed=9, kind="terminal",
+                         deadline_ms=DEADLINE_CLASSES[0].max_deadline_ms))
+    wall, done = 0.0, []
+    while sched.busy:
+        t0 = time.perf_counter()
+        done += sched.step()
+        wall += time.perf_counter() - t0
+    assert sorted(r.rid for r in done) == [0, 1, 2, 9]
+    spent = [sched.counters[k] for k in ("admit_s", "advance_s", "terminal_s")]
+    assert all(s > 0.0 for s in spent), spent
+    assert sum(spent) <= wall
+
+
 # -----------------------------------------------------------------------------
 # continuous batching: mid-flight admission is bitwise-invisible
 # -----------------------------------------------------------------------------
