@@ -105,8 +105,8 @@ def _bits_kernel(k1, k2, shape, block_rows: int):
     from repro.kernels import prng
 
     def kernel(k_ref, o_ref):
-        row0 = pl.program_id(0) * block_rows
-        hi, lo = prng.counters(o_ref.shape, row0)
+        start = pl.program_id(0) * (block_rows * cols)
+        hi, lo = prng.counters(o_ref.shape, start)
         o_ref[...] = prng.bits(k_ref[0, 0], k_ref[0, 1], hi, lo, 32)
 
     rows, cols = shape

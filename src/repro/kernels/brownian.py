@@ -26,10 +26,15 @@ Kernel contract
   the tests pin that the Pallas lowering/interpreter preserves it.
 * Row grid: a draw of shape ``(..., cols)`` is laid out as ``(rows,
   cols)`` and tiled by :func:`repro.kernels.reversible_heun_step.row_block`.
-  Each block derives its counters from its first global row — ``row0 +
-  program_id · block`` — so the bits depend neither on the tiling nor on
-  the data-parallel sharding: a shard passes its global row offset as
-  ``row0`` (:mod:`repro.kernels.ops` does this under a mesh).
+  :func:`brownian_increment` lays a narrow draw (``cols`` under 128) out
+  lane-dense instead, where that takes fewer ``(sublanes, 128)`` vector
+  tiles: its C-order elements 128 to a row, the tail of the last row cut
+  off outside the kernel, so a ``(1024, 3)`` draw fills every lane
+  instead of 3 of 128.  Each block derives its counters from the flat
+  index of its first element — the draw's offset plus ``program_id``
+  blocks — so the bits depend neither on the layout, nor on the tiling,
+  nor on the data-parallel sharding: a shard passes its global row
+  offset as ``row0`` (:mod:`repro.kernels.ops` does this under a mesh).
 * Key folding (``fold_in(key, n)``, 20 scalar Threefry rounds) runs in XLA
   before the launch; the kernel receives the folded key and ``sqrt(dt)``
   as SMEM scalars.
@@ -40,6 +45,7 @@ Kernel contract
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +56,8 @@ from . import prng, ref
 from .reversible_heun_step import as_2d, row_block
 
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+#: The vector unit's lane count: the row width of a lane-dense draw.
+_LANES = 128
 
 
 def _smem_row(*xs, dtype):
@@ -70,13 +78,33 @@ class _Row:
         return self.ref[0, i]
 
 
-def _row0(off_ref, br):
-    return off_ref[0, 0] + pl.program_id(0) * br
+def _offset(row0, cols):
+    """The SMEM operand holding the flat index of a draw's first element:
+    global row ``row0`` of a draw ``cols`` wide."""
+    return _smem_row(jnp.asarray(row0, jnp.int32) * cols, dtype=jnp.int32)
 
 
-def _increment_kernel(dtype, br, off_ref, k_ref, s_ref, o_ref):
+def _start(off_ref, block_size):
+    """Flat index of this grid cell's first element, for blocks of
+    ``block_size`` elements."""
+    return off_ref[0, 0] + pl.program_id(0) * block_size
+
+
+def _lane_dense(rows: int, cols: int, dtype) -> bool:
+    """Whether a ``(rows, cols)`` draw takes fewer vector tiles laid out
+    128 elements to a row than in its own rows: a narrow draw of more rows
+    than one tile holds.  A tile is 8 rows of 32-bit values (16 of
+    16-bit ones) by 128 lanes."""
+    sublanes = 8 * 4 // dtype.itemsize
+    dense_rows = pl.cdiv(rows * cols, _LANES)
+    return (cols < _LANES
+            and pl.cdiv(dense_rows, sublanes) < pl.cdiv(rows, sublanes))
+
+
+def _increment_kernel(dtype, off_ref, k_ref, s_ref, o_ref):
     o_ref[...] = ref.increment_block(k_ref[0, 0], k_ref[0, 1], o_ref.shape,
-                                     dtype, s_ref[0, 0], _row0(off_ref, br))
+                                     dtype, s_ref[0, 0],
+                                     _start(off_ref, math.prod(o_ref.shape)))
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "interpret"))
@@ -86,29 +114,34 @@ def brownian_increment(k1, k2, n, shape, dtype, dt, row0=0,
 
     ``k1, k2``: raw uint32 key halves; ``n``: step counter; ``dt``: the
     grid spacing (scalar, may be traced); ``row0``: the global row of this
-    draw's first row (non-zero only for a shard of a larger draw).
+    draw's first row (non-zero only for a shard of a larger draw).  A
+    narrow draw is generated lane-dense (module docstring).
     """
     dtype = jnp.dtype(dtype)
     shape = tuple(shape)
     rows, cols = prng.as_rows(shape)
+    offset = _offset(row0, cols)
+    size = rows * cols
+    if _lane_dense(rows, cols, dtype):
+        rows, cols = pl.cdiv(size, _LANES), _LANES
     br = row_block(rows, cols, dtype, interpret)
     f1, f2 = prng.fold_in(k1, k2, n)
     out = pl.pallas_call(
-        functools.partial(_increment_kernel, dtype, br),
+        functools.partial(_increment_kernel, dtype),
         grid=(pl.cdiv(rows, br),),
         in_specs=[_SMEM] * 3,
         out_specs=pl.BlockSpec((br, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), dtype),
         interpret=interpret,
-    )(_smem_row(row0, dtype=jnp.int32), _smem_row(f1, f2, dtype=jnp.uint32),
+    )(offset, _smem_row(f1, f2, dtype=jnp.uint32),
       _smem_row(jnp.sqrt(jnp.asarray(dt, dtype)), dtype=dtype))
-    return out.reshape(shape)
+    return out.reshape(-1)[:size].reshape(shape)
 
 
-def _value_kernel(dtype, br, depth, off_ref, k_ref, f_ref, g_ref, o_ref):
+def _value_kernel(dtype, depth, off_ref, k_ref, f_ref, g_ref, o_ref):
     o_ref[...] = ref.bridge_block(_Row(k_ref), _Row(f_ref), _Row(g_ref),
                                   o_ref.shape, dtype, depth,
-                                  _row0(off_ref, br))
+                                  _start(off_ref, math.prod(o_ref.shape)))
 
 
 @functools.partial(
@@ -123,20 +156,20 @@ def brownian_value(k1, k2, t, t0, t1, shape, dtype, depth: int = 24, row0=0,
     br = row_block(rows, cols, dtype, interpret)
     keys, floats, gos = ref.bridge_descent(k1, k2, t, t0, t1, dtype, depth)
     out = pl.pallas_call(
-        functools.partial(_value_kernel, dtype, br, depth),
+        functools.partial(_value_kernel, dtype, depth),
         grid=(pl.cdiv(rows, br),),
         in_specs=[_SMEM] * 4,
         out_specs=pl.BlockSpec((br, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), dtype),
         interpret=interpret,
-    )(_smem_row(row0, dtype=jnp.int32), keys[None], floats[None], gos[None])
+    )(_offset(row0, cols), keys[None], floats[None], gos[None])
     return out.reshape(shape)
 
 
-def _phase1_gen_kernel(dtype, br, sign, off_ref, k_ref, s_ref,
+def _phase1_gen_kernel(dtype, sign, off_ref, k_ref, s_ref,
                        z_ref, zh_ref, mu_ref, sig_ref, zh1_ref, dw_ref):
     dw = ref.increment_block(k_ref[0, 0], k_ref[0, 1], dw_ref.shape, dtype,
-                             s_ref[0, 0], _row0(off_ref, br))
+                             s_ref[0, 0], _start(off_ref, math.prod(dw_ref.shape)))
     zh1_ref[...] = ref.rev_heun_phase1(z_ref[...], zh_ref[...], mu_ref[...],
                                        sig_ref[...], dw, s_ref[0, 1], sign)
     dw_ref[...] = dw
@@ -164,13 +197,13 @@ def rev_heun_phase1_gen(z, zh, mu, sigma, k1, k2, n, dt_grid, dt,
     spec = pl.BlockSpec((br, cols), lambda i: (i, 0))
     out = jax.ShapeDtypeStruct((rows, cols), dtype)
     zh1, dw = pl.pallas_call(
-        functools.partial(_phase1_gen_kernel, dtype, br, sign),
+        functools.partial(_phase1_gen_kernel, dtype, sign),
         grid=(pl.cdiv(rows, br),),
         in_specs=[_SMEM] * 3 + [spec] * 4,
         out_specs=(spec, spec),
         out_shape=(out, out),
         interpret=interpret,
-    )(_smem_row(row0, dtype=jnp.int32), _smem_row(f1, f2, dtype=jnp.uint32),
+    )(_offset(row0, cols), _smem_row(f1, f2, dtype=jnp.uint32),
       _smem_row(jnp.sqrt(jnp.asarray(dt_grid, dtype)), dt, dtype=dtype),
       *flat)
     return zh1.reshape(shape), dw.reshape(shape)

@@ -21,10 +21,10 @@ legal inside a Pallas TPU kernel body (elementwise ``lax`` ops, 2-D
 * :func:`fold_in` — ``threefry2x32(key, seed_pair(n))``, matching
   ``jax.random.fold_in``'s counter scheme;
 * :func:`counters` — each element's counter is the ``(hi, lo)`` word pair
-  of its C-order flat index (``iota_2x32_shape``).  A draw of any shape is
-  laid out as ``(rows, cols)`` with ``cols = shape[-1]``; a block of rows
-  starting at ``row0`` derives its counters from ``row0`` alone, so the
-  bits do not depend on how the rows are tiled or sharded;
+  of its C-order flat index (``iota_2x32_shape``).  A block of the draw,
+  of any 2-D layout, derives its counters from the flat index of its
+  first element alone, so the bits depend neither on the layout nor on
+  how the block grid tiles or shards the draw;
 * :func:`bits` — 32-bit draws are ``y1 ^ y2``, 64-bit draws
   ``y1 << 32 | y2``, of the two hash lanes;
 * :func:`normal_block` / :func:`normal_like` — the mantissa-shift bitcast
@@ -117,17 +117,18 @@ def as_rows(shape) -> Tuple[int, int]:
     return rows, cols
 
 
-def counters(block_shape, row0=0) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``(hi, lo)`` counters of a ``(rows, cols)`` block whose first row is
-    global row ``row0`` (an int32 scalar, possibly traced): the flat index
-    ``(row0 + r) * cols + c`` — JAX's ``iota_2x32_shape`` restricted to the
-    block.  ``hi`` is zero because :func:`as_rows` bounds the draw size."""
+def counters(block_shape, start=0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(hi, lo)`` counters of a ``(rows, cols)`` block of a draw's
+    C-order elements, whose first element has flat index ``start`` (an
+    int32 scalar, possibly traced): the flat index ``start + r * cols + c``
+    — JAX's ``iota_2x32_shape`` restricted to the block.  The int32
+    arithmetic wraps, so ``lo`` is the index modulo 2**32; ``hi`` is zero
+    because :func:`as_rows` bounds the draw size."""
     cols = block_shape[-1]
-    r = lax.broadcasted_iota(jnp.int32, block_shape, 0) + row0
-    c = lax.broadcasted_iota(jnp.int32, block_shape, 1)
-    lo = (lax.convert_element_type(r, jnp.uint32) * np.uint32(cols)
-          + lax.convert_element_type(c, jnp.uint32))
-    return jnp.zeros(block_shape, jnp.uint32), lo
+    i = (lax.broadcasted_iota(jnp.int32, block_shape, 0) * np.int32(cols)
+         + lax.broadcasted_iota(jnp.int32, block_shape, 1) + start)
+    return (jnp.zeros(block_shape, jnp.uint32),
+            lax.convert_element_type(i, jnp.uint32))
 
 
 def bits(k1, k2, hi, lo, bit_width: int) -> jnp.ndarray:
@@ -164,11 +165,12 @@ def normal_from_bits(b, dtype) -> jnp.ndarray:
     return lax.mul(np.array(np.sqrt(2), dtype), lax.erf_inv(u))
 
 
-def normal_block(k1, k2, block_shape, dtype, row0=0) -> jnp.ndarray:
-    """Rows ``row0 .. row0 + block_shape[0]`` of a ``(rows, cols)`` normal
-    draw — what a kernel's grid cell computes for its block."""
+def normal_block(k1, k2, block_shape, dtype, start=0) -> jnp.ndarray:
+    """The normals at flat indices ``start .. start + block_shape[0] *
+    block_shape[1]`` of a draw, laid out as ``block_shape`` — what a
+    kernel's grid cell computes for its block."""
     dtype = jax.dtypes.canonicalize_dtype(dtype)  # as jax.random does
-    hi, lo = counters(block_shape, row0)
+    hi, lo = counters(block_shape, start)
     return normal_from_bits(bits(k1, k2, hi, lo, dtype.itemsize * 8), dtype)
 
 

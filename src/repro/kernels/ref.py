@@ -76,16 +76,16 @@ def rev_heun_bwd_phase2(g_z1, ghat, dw, dt):
 #
 # Each oracle is split the way the kernel is: scalar work (key folding,
 # the bridge descent) runs in XLA, and a ``*_block`` function computes one
-# ``(rows, cols)`` block of the draw from those scalars and the block's
-# first global row ``row0``.  The kernel bodies call the same ``*_block``
+# 2-D block of the draw from those scalars and the flat index ``start`` of
+# the block's first element.  The kernel bodies call the same ``*_block``
 # functions on values loaded from their refs, so kernel and oracle are one
 # op sequence (tests/test_kernel_parity.py pins them bitwise).
 
 
-def increment_block(f1, f2, block_shape, dtype, scale, row0=0):
-    """Rows ``row0 ..`` of a grid increment: normals under the folded key
-    ``(f1, f2)`` times ``scale = sqrt(dt)``."""
-    return prng.normal_block(f1, f2, block_shape, dtype, row0) * scale
+def increment_block(f1, f2, block_shape, dtype, scale, start=0):
+    """Elements ``start ..`` of a grid increment: normals under the folded
+    key ``(f1, f2)`` times ``scale = sqrt(dt)``."""
+    return prng.normal_block(f1, f2, block_shape, dtype, start) * scale
 
 
 def brownian_increment(k1, k2, n, shape, dtype, dt):
@@ -141,18 +141,18 @@ def bridge_descent(k1, k2, t, t0, t1, dtype, depth: int = 24):
 
 
 def bridge_block(keys, floats, gos, block_shape, dtype, depth: int,
-                 row0=0):
-    """Rows ``row0 ..`` of ``W(t) − W(t0)`` from :func:`bridge_descent`'s
-    scalars: the root draw, one midpoint draw per level, and the
-    elementwise combine.  ``keys``/``floats``/``gos`` may be arrays or
-    kernel refs — both index the same way."""
+                 start=0):
+    """Elements ``start ..`` of ``W(t) − W(t0)`` from
+    :func:`bridge_descent`'s scalars: the root draw, one midpoint draw per
+    level, and the elementwise combine.  ``keys``/``floats``/``gos`` may be
+    arrays or kernel refs — both index the same way."""
     w_t1 = prng.normal_block(keys[0], keys[1], block_shape, dtype,
-                             row0) * floats[0]
+                             start) * floats[0]
 
     def body(i, c):
         wa, wb = c
         zm = prng.normal_block(keys[2 + 2 * i], keys[3 + 2 * i], block_shape,
-                               dtype, row0)
+                               dtype, start)
         wm = 0.5 * (wa + wb) + floats[2 + i] * zm
         go_left = gos[i] != 0
         return (jnp.where(go_left, wa, wm), jnp.where(go_left, wm, wb))

@@ -7,10 +7,12 @@ for GAN generators.  AdamW + cosine schedule serve the LM training path.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import math
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _zeros_like_tree(t):
@@ -80,6 +82,97 @@ def adadelta(lr=1.0, rho=0.9, eps=1e-6):
             grads, acc_g, state.v)
         acc_d = jax.tree.map(lambda a, u: rho * a + (1 - rho) * u * u, state.v, upd)
         return upd, OptState(state.step + 1, acc_g, acc_d)
+
+    return init, update
+
+
+# -----------------------------------------------------------------------------
+# flat optimiser state (optax.flatten)
+# -----------------------------------------------------------------------------
+
+
+class _Layout(NamedTuple):
+    """How a flat vector splits back into a pytree: hashable, so it rides
+    as a pytree's static aux data."""
+    treedef: Any
+    shapes: tuple
+    dtypes: tuple
+
+
+def _ravel(tree):
+    """``tree``'s leaves raveled and concatenated in leaf order, and its
+    :class:`_Layout`."""
+    leaves, treedef = jax.tree.flatten(tree)
+    layout = _Layout(treedef, tuple(jnp.shape(x) for x in leaves),
+                     tuple(jnp.result_type(x) for x in leaves))
+    return jnp.concatenate([jnp.ravel(x) for x in leaves]), layout
+
+
+def _unravel(vec, layout: _Layout):
+    cuts = np.cumsum([math.prod(s) for s in layout.shapes])[:-1]
+    return jax.tree.unflatten(layout.treedef, [
+        p.reshape(s).astype(d)
+        for p, s, d in zip(jnp.split(vec, cuts), layout.shapes,
+                           layout.dtypes)])
+
+
+@jax.tree_util.register_pytree_with_keys_class
+class FlatOptState:
+    """An :class:`OptState` whose moments are each one raveled vector.
+
+    Three device arrays however many parameter leaves there are, so a
+    jitted step takes and returns three buffers for the optimiser state
+    instead of ``1 + 2 × leaves``.  ``m`` and ``v`` read back as
+    parameter-shaped trees; the pytree's leaves are ``step``, ``m_flat``
+    and ``v_flat``, keyed by those names (checkpoint leaf paths).
+    """
+
+    def __init__(self, step, m_flat, v_flat, layout: _Layout):
+        self.step, self.m_flat, self.v_flat = step, m_flat, v_flat
+        self.layout = layout
+
+    @property
+    def m(self):
+        return _unravel(self.m_flat, self.layout)
+
+    @property
+    def v(self):
+        return _unravel(self.v_flat, self.layout)
+
+    def tree_flatten_with_keys(self):
+        key = jax.tree_util.GetAttrKey
+        return (((key("step"), self.step), (key("m_flat"), self.m_flat),
+                 (key("v_flat"), self.v_flat)), self.layout)
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        return cls(*children, layout)
+
+
+def flatten(transform):
+    """``transform`` (an :class:`OptState` optimiser) run on the raveled
+    parameter vector: the ``optax.flatten`` idea.
+
+    The arithmetic is the inner optimiser's, elementwise on one vector
+    instead of per leaf, so updates and moments are bitwise the per-leaf
+    ones; the update is unraveled to the parameters' tree, so per-leaf
+    transforms chained after it (:func:`lipschitz_projection`) see leaves
+    as before.  The state is a :class:`FlatOptState`.
+    """
+    inner_init, inner_update = transform
+
+    def init(params):
+        flat, layout = _ravel(params)
+        s = inner_init(flat)
+        return FlatOptState(s.step, s.m, s.v, layout)
+
+    def update(grads, state, params=None):
+        g, layout = _ravel(grads)
+        p = None if params is None else _ravel(params)[0]
+        upd, s = inner_update(g, OptState(state.step, state.m_flat,
+                                          state.v_flat), p)
+        return _unravel(upd, layout), FlatOptState(s.step, s.m, s.v,
+                                                   state.layout)
 
     return init, update
 

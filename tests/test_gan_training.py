@@ -133,6 +133,20 @@ def test_two_step_loop_decreases_wasserstein_deterministically(key):
     assert a[1] < a[0] and a[2] < a[1], f"W estimate not decreasing: {a}"
 
 
+def test_clip_step_dispatch_buffer_count(key):
+    """The jitted step takes and returns three buffers per player's
+    optimiser state, not one per parameter leaf twice over: the host pays
+    its dispatch cost per buffer.  Out: the params, 2 x 3 state arrays and
+    3 metrics; in: the params, 2 x 3 state arrays and the key."""
+    cfg, params, g_state, d_state, step = _tiny_setup(key)
+    n_params = len(jax.tree.leaves(params))
+    args = (params, g_state, d_state, key)
+    out = jax.eval_shape(step, *args)
+    assert len(jax.tree.leaves(out)) == n_params + 2 * 3 + 3
+    assert len(jax.tree.leaves(args)) == n_params + 2 * 3 + 1
+    assert len(jax.tree.leaves(out)) <= 37   # 28 parameter leaves, as at ou_gan's size
+
+
 def test_gp_step_runs_and_matches_metric_keys(key):
     """The WGAN-GP baseline path of the shared step builder is runnable and
     reports the same metric schema (benchmarks/clipping.py relies on it)."""

@@ -200,6 +200,56 @@ def test_brownian_increment_kernel_bitwise(shape, dtype):
             _assert_bitwise(got, want, f"brownian_increment {shape} {dtype} {n}")
 
 
+def _pallas_out_shapes(fn, *args):
+    """Output shapes of every ``pallas_call`` in ``fn``'s jaxpr, nested
+    jits included."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield from (tuple(v.aval.shape) for v in e.outvars)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
+
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+# (draw, kernel layout): narrow draws of more than one (8, 128) tile's rows
+# go 128 elements to a row, the tail of the last row cut off; a draw that
+# fits one tile either way, and a wide one, keep their rows
+LANE_DENSE = [((1024, 3), (24, 128)), ((1000, 3), (24, 128)),
+              ((20, 3), (1, 128)), ((16, 17), (3, 128)),
+              ((64, 48), (24, 128)), ((5, 3), (5, 3)), ((8, 1), (8, 1)),
+              ((4, 128), (4, 128))]
+
+
+@pytest.mark.parametrize("shape,layout", LANE_DENSE,
+                         ids=[str(s) for s, _ in LANE_DENSE])
+def test_lane_dense_increment_bitwise(shape, layout):
+    """The increment kernel's layout is chosen from the draw's shape, and
+    either way the bits are the oracle's; a shard's draw at a row offset
+    whose flat index is no multiple of 128 is the matching rows of the
+    whole draw."""
+    dtype = jnp.float32
+    k1, k2 = prng.key_data_pair(jax.random.PRNGKey(45))
+    rows, cols = shape
+    dt = jnp.asarray(0.3, dtype)
+
+    def kern(d, row0=0, local=shape):
+        return bk.brownian_increment(k1, k2, 7, local, dtype, d, row0=row0,
+                                     interpret=True)
+
+    assert _pallas_out_shapes(kern, dt) == [layout]
+    got = jax.jit(kern)(dt)
+    want = jax.jit(lambda d: ref.brownian_increment(
+        k1, k2, 7, shape, dtype, d))(dt)
+    _assert_bitwise(got, want, f"lane-dense increment {shape}")
+    if rows > 1:
+        row0 = rows - rows // 3 - 1
+        tail = jax.jit(functools.partial(
+            kern, row0=row0, local=(rows - row0, cols)))(dt)
+        _assert_bitwise(tail, got[row0:], f"row offset {row0} of {shape}")
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("shape", [(4, 4), (2, 3, 8), (16,)])
 def test_brownian_value_kernel_bitwise(shape, dtype):

@@ -99,7 +99,8 @@ def _phase1_gen(z, zh, mu, sig, k1, k2, n, dt_grid, dt):
     return bk.rev_heun_phase1_gen(z, zh, mu, sig, k1, k2, n, dt_grid, dt)
 
 
-@pytest.mark.parametrize("shape", [(1024, 4), (1024, 17)], ids=str)
+@pytest.mark.parametrize("shape", [(1024, 3), (1024, 4), (1024, 17)],
+                         ids=str)
 @pytest.mark.parametrize("kernel", ["increment", "phase1_gen"])
 def test_brownian_kernel_compiles(kernel, shape, one_chip,
                                   no_persistent_cache):
