@@ -13,6 +13,8 @@ scope                     covers
                           reconstruction and local VJPs)
 ``sde.brownian``          Brownian increments and point values
 ``sde.field``             drift and diffusion evaluations, and their VJPs
+``sde.encode``            the Latent SDE's encoder: the GRU over the
+                          observations and the ``qz0`` and ``zeta`` heads
 ========================  =================================================
 
 Under plain autodiff (the ``discretise`` and ``checkpoint`` backends) the
@@ -31,6 +33,7 @@ SOLVE = "sde.solve"
 ADJOINT = "sde.adjoint"
 BROWNIAN = "sde.brownian"
 FIELD = "sde.field"
+ENCODE = "sde.encode"
 
 
 def scope(name: str):

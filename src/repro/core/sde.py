@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..kernels.ops import vmap_rows
 from ..nn.core import tcat as _tcat  # the shared time-augmentation convention
+from . import scopes
 from .brownian import BrownianPath
 from .paths import LinearPathControl
 from .solve import get_solver, solve
@@ -344,23 +345,42 @@ def _latent_encode(params, cfg: LatentSDEConfig, key, y_true):
     initial hidden state ζ_θ(V̂) with V̂ ~ N(m, s) from ξ_φ(ctx_0), and the
     per-sample KL(N(m, s) ‖ N(0, 1)) of the initial latent.
     """
-    ctx = nn.gru_scan(params["enc"], y_true, reverse=True)  # (T+1, B, c)
-    ms = nn.mlp(params["qz0"], ctx[0], nn.lipswish)
-    m, log_s = jnp.split(ms, 2, -1)
-    s = jnp.exp(jnp.clip(log_s, -8, 4))
-    v = m + s * jax.random.normal(key, m.shape, cfg.dtype)
-    kl_v = 0.5 * jnp.sum(m**2 + s**2 - 2.0 * jnp.log(s) - 1.0, -1)
-    x0 = nn.mlp(params["zeta"], v, nn.lipswish)
+    with scopes.scope(scopes.ENCODE):
+        ctx = nn.gru_scan(params["enc"], y_true, reverse=True)  # (T+1, B, c)
+        ms = nn.mlp(params["qz0"], ctx[0], nn.lipswish)
+        m, log_s = jnp.split(ms, 2, -1)
+        s = jnp.exp(jnp.clip(log_s, -8, 4))
+        v = m + s * jax.random.normal(key, m.shape, cfg.dtype)
+        kl_v = 0.5 * jnp.sum(m**2 + s**2 - 2.0 * jnp.log(s) - 1.0, -1)
+        x0 = nn.mlp(params["zeta"], v, nn.lipswish)
     return ctx, x0, kl_v
 
 
-def _step_index_lookup(t1: float, T: int):
-    """``(path, t) -> path[round(t / t1 * T)]`` — index a (T+1, ...) tensor
-    (encoder context, observations) by solver time.  Shared by the training
-    posterior fields and the serving posterior decode."""
+#: How far below a solver-grid point, in solver steps, a time may fall and
+#: still read that point's row: float32 forms ``t`` with a few ulps of error
+#: (``n*dt + dt``, ``t1 - k*dt``, a running sum), never a whole step's.
+_GRID_SNAP = 1e-3
+
+
+def _step_index_lookup(t1: float, T: int, num_steps: int):
+    """``(path, t) -> path[k]``: index a (T+1, ...) tensor (encoder
+    context, observations) by solver time.  Shared by the training
+    posterior fields and the serving posterior decode.
+
+    ``k = floor(t / t1 * num_steps + _GRID_SNAP) // stride``, clipped to
+    ``[0, T]``, with ``stride = num_steps // T``: the solver step that ``t``
+    lies in, snapped up to the next grid point when it falls within
+    ``_GRID_SNAP`` steps below it, then the observation interval of that
+    step.  So row ``k`` is read on ``[t_k, t_{k+1})`` and row ``k + 1`` at
+    ``t_{k+1}``, however ``t`` was rounded on its way there.  Truncating
+    ``t / t1 * T`` instead reads row ``k - 1`` wherever ``t_k`` came out an
+    ulp low, which depends on how the compiler fuses the time arithmetic.
+    """
+    stride = validate_latent_grid(num_steps, T)
 
     def at(p, t):
-        idx = jnp.clip(jnp.asarray(t / t1 * T).astype(jnp.int32), 0, T)
+        n = jnp.floor(jnp.asarray(t) * (num_steps / t1) + _GRID_SNAP)
+        idx = jnp.clip(n.astype(jnp.int32) // stride, 0, T)
         return jax.lax.dynamic_index_in_dim(p, idx, 0, keepdims=False)
 
     return at
@@ -377,7 +397,7 @@ def _latent_posterior_fields(cfg: LatentSDEConfig, T: int, n_aux: int,
     carry zero diffusion rows.
     """
 
-    _ctx_at = _step_index_lookup(cfg.t1, T)
+    _ctx_at = _step_index_lookup(cfg.t1, T, cfg.num_steps)
 
     def post_drift(p, t, u):
         x = u[..., : cfg.hidden_dim]
@@ -659,8 +679,7 @@ def latent_sde_posterior_decode(params, cfg: LatentSDEConfig, keys, y_obs):
     (num_steps+1, B, data_dim).
     """
     T = y_obs.shape[0] - 1
-    validate_latent_grid(cfg.num_steps, T)
-    ctx_at = _step_index_lookup(cfg.t1, T)
+    ctx_at = _step_index_lookup(cfg.t1, T, cfg.num_steps)  # checks the grid
 
     def drift(p, t, x):
         c = ctx_at(p["ctx"], t)

@@ -1,7 +1,8 @@
 """Latent-SDE (VAE) training subsystem tests (paper Appendix B; DESIGN.md §8).
 
 The grid-misalignment regression (the eager ValueError replacing the old
-broadcast TypeError / zero-stride crash), the one-``jax.vjp`` ELBO step,
+broadcast TypeError / zero-stride crash), the context row read at every
+solver time however float32 rounded it, the one-``jax.vjp`` ELBO step,
 fused-vs-unfused equivalence, the backsolve baseline, and the launch CLI on
 1 and 2 (simulated) devices.
 """
@@ -12,10 +13,12 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.sde import (LatentSDEConfig, latent_sde_init, latent_sde_loss,
+from repro.core.sde import (LatentSDEConfig, _step_index_lookup,
+                            latent_sde_init, latent_sde_loss,
                             latent_sde_loss_terminal, validate_latent_grid)
 from repro.data.synthetic import air_quality_like
 from repro.launch.steps import make_latent_sde_optimizer, make_latent_sde_step
@@ -76,6 +79,46 @@ def test_misaligned_grid_raises_under_jit(key):
 
 
 # -----------------------------------------------------------------------------
+# the context row read at solver time t
+# -----------------------------------------------------------------------------
+
+
+def _grid_times(num_steps: int, t1: float = 1.0) -> dict:
+    """Solver-grid point ``n`` as float32 forms it in four ways: ``t0 +
+    n*dt``; the right end of step ``n - 1`` (``(n-1)*dt + dt``, as a step
+    forms ``t + dt``); a backward reconstruction's ``t1 - (N-n)*dt``; and a
+    running sum of ``dt``."""
+    f = np.float32
+    dt = f(t1) / f(num_steps)
+    n = np.arange(num_steps + 1, dtype=f)
+    right = np.concatenate([[f(0)], n[:-1] * dt + dt]).astype(f)
+    summed = np.concatenate([[f(0)], np.cumsum(np.full(num_steps, dt, f),
+                                               dtype=f)]).astype(f)
+    return {"t0 + n*dt": n * dt, "n*dt + dt": right,
+            "t1 - k*dt": f(t1) - (num_steps - n) * dt, "running sum": summed}
+
+
+@pytest.mark.parametrize("T,stride", [(23, 1), (4, 2)])
+def test_context_row_at_every_grid_time(T, stride):
+    """At solver step ``n`` the lookup reads row ``n // stride`` whichever
+    way ``t_n`` was rounded; between grid points it keeps the row of the
+    interval's left end.  Truncating ``t / t1 * T`` read row 6 at step 7 of
+    the 23-step grid (``7*dt`` formed as ``6*dt + dt`` comes out an ulp
+    low), so the program and its reference could read different rows."""
+    num_steps = T * stride
+    at = jax.jit(_step_index_lookup(1.0, T, num_steps))
+    path = jnp.arange(T + 1, dtype=jnp.float32)
+    want = np.arange(num_steps + 1) // stride
+    for form, ts in _grid_times(num_steps).items():
+        rows = [int(at(path, jnp.float32(t))) for t in ts]
+        np.testing.assert_array_equal(rows, want, err_msg=form)
+    dt = np.float32(1.0 / num_steps)
+    mids = np.arange(num_steps, dtype=np.float32) * dt + dt / 2
+    rows = [int(at(path, jnp.float32(t))) for t in mids]
+    np.testing.assert_array_equal(rows, np.arange(num_steps) // stride)
+
+
+# -----------------------------------------------------------------------------
 # the step builder: eager config validation
 # -----------------------------------------------------------------------------
 
@@ -129,6 +172,36 @@ def test_elbo_step_decreases_loss_deterministically(key):
     a, b = run(), run()
     assert a == b, f"nondeterministic trajectory: {a} vs {b}"
     assert a[-1] < a[0], f"-ELBO not decreasing: {a}"
+
+
+def test_flat_adam_step_bitwise_equals_per_leaf(key):
+    """The step's Adam keeps three flat arrays, and two ELBO steps give the
+    per-leaf Adam's parameters and moments bit for bit."""
+    from repro import optim
+
+    cfg, params, state, step = _tiny_setup(key)
+    ref_init, ref_update = optim.adam(1e-2)
+    ref_step = jax.jit(make_latent_sde_step(cfg, ref_update, BATCH, SEQ))
+    assert len(jax.tree.leaves(state)) == 3
+    p_ref, s_ref = params, ref_init(params)
+    for i in range(2):
+        k = jax.random.fold_in(key, 2 + i)
+        params, state, _ = step(params, state, k)
+        p_ref, s_ref, _ = ref_step(p_ref, s_ref, k)
+    got, want = (params, state.m, state.v), (p_ref, s_ref.m, s_ref.v)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_step_dispatch_buffer_count(key):
+    """In: the parameters, 3 optimiser arrays and the key; out: the
+    parameters, 3 optimiser arrays and 5 metrics."""
+    _, params, state, step = _tiny_setup(key)
+    n_params = len(jax.tree.leaves(params))
+    args = (params, state, key)
+    assert len(jax.tree.leaves(args)) == n_params + 3 + 1
+    assert len(jax.tree.leaves(jax.eval_shape(step, *args))) == n_params + 3 + 5
 
 
 def test_fused_step_matches_unfused(key):

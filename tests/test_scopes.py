@@ -1,6 +1,7 @@
 """The solve stack's named scopes (``repro.core.scopes``) in the compiled
 SDE-GAN training step, at the size the benchmark's CPU tests cut its
-``ou_gan`` cell to, with the exact adjoint as the cell runs it.
+``ou_gan`` cell to, with the exact adjoint as the cell runs it; and the
+encoder's scope in the Latent-SDE step, which the GAN step lacks.
 
 A scope is HLO metadata: the step must compile to the same program with
 and without the scopes.  XLA names an instruction after the last component
@@ -15,11 +16,13 @@ import re
 import jax
 import pytest
 
+from bench.models import latent_sde
 from bench.models.sde_gan import program_config
 from bench.tests import tiny
 from repro.core import scopes
-from repro.core.sde import discriminator_init, generator_init
-from repro.launch.steps import make_gan_optimizers, make_sde_gan_step
+from repro.core.sde import discriminator_init, generator_init, latent_sde_init
+from repro.launch.steps import (make_gan_optimizers, make_latent_sde_optimizer,
+                                make_latent_sde_step, make_sde_gan_step)
 
 SCOPES = (scopes.SOLVE, scopes.ADJOINT, scopes.BROWNIAN, scopes.FIELD)
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -62,6 +65,24 @@ def _compiled_step() -> str:
                       jax.random.PRNGKey(1)).compile().as_text()
 
 
+def _compiled_latent_step() -> str:
+    """The ``latent_sde_air`` cell's step (exact adjoint, fused kernels) at
+    a few units and steps."""
+    config = json.loads((tiny.REPO / "bench" / "configs" / "latent_sde_air.json")
+                        .read_text())
+    config["model"].update(hidden_dim=2, context_dim=3, initial_noise_dim=2,
+                           width=4, num_steps=3)
+    traffic = {"batch": 8, "seq_len": 4, "adjoint": "exact",
+               "use_pallas_kernels": True}
+    cfg = latent_sde.program_config(config, traffic, "highest")
+    opt_init, opt_update = make_latent_sde_optimizer(config["optimiser"]["lr"])
+    step = jax.jit(make_latent_sde_step(cfg, opt_update, traffic["batch"],
+                                        traffic["seq_len"], adjoint="exact"))
+    params = latent_sde_init(jax.random.PRNGKey(0), cfg)
+    return step.lower(params, opt_init(params),
+                      jax.random.PRNGKey(1)).compile().as_text()
+
+
 def _program(hlo: str) -> str:
     """The compiled module with metadata, the source-location tables and
     instruction names taken out."""
@@ -94,3 +115,15 @@ def test_scopes_change_no_program(monkeypatch, no_compile_cache):
     plain = _compiled_step()
     assert not any(s in n for n in _OP_NAME.findall(plain) for s in SCOPES)
     assert _program(scoped) == _program(plain)
+
+
+def test_latent_step_names_its_encoder(no_compile_cache):
+    """``sde.encode`` covers the GRU and the ``qz0``/``zeta`` heads, and
+    their VJP under ``transpose(...)``; the GAN step has no encoder."""
+    names = _OP_NAME.findall(_compiled_latent_step())
+    encode = [n for n in names
+              if scopes.ENCODE in n.split("/") or f"({scopes.ENCODE})" in n]
+    assert encode
+    assert any("transpose(" in n[:n.index(scopes.ENCODE)] for n in encode)
+    assert any(scopes.SOLVE in n for n in names)
+    assert not any(scopes.ENCODE in n for n in _OP_NAME.findall(_compiled_step()))
