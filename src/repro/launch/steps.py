@@ -86,11 +86,19 @@ def make_sde_gan_step(cfg, g_update, d_update, batch: int, seq_len: int,
 
     ``constraint="clip"`` (the paper's recipe) runs the generator forward —
     generator solve + joint generator/discriminator solve + real-path CDE
-    solve — exactly **once** per step via ``jax.vjp``, then pulls two
-    cotangents (one per player) through the reversible-Heun exact adjoint.
-    That halves the solve count versus ``jax.grad`` per player, and the
-    Lipschitz constraint costs one elementwise projection inside
-    ``d_update`` (no second backward anywhere).
+    solve — exactly **once** per step via ``jax.vjp``, and pulls **one**
+    cotangent, the critic loss's, through the reversible-Heun exact
+    adjoint.  ``gan_losses`` has ``gen_loss = -mean(fake_score)`` and
+    ``disc_loss = mean(fake_score) - mean(real_score)``; the real term does
+    not depend on the generator, so ``d gen_loss / d gen = -(d disc_loss /
+    d gen)``, and the one pull returns both players' gradients.  The
+    negation is exact: every VJP is linear in its cotangent and
+    round-to-nearest is symmetric under sign, so the generator's gradient
+    is bitwise the one a separate pull of ``gen_loss`` would give
+    (``tests/test_gan_training.py`` pins it against that two-pull form).
+    Each backward solve (joint reconstruction, real-path CDE) runs once a
+    step, and the Lipschitz constraint costs one elementwise projection
+    inside ``d_update``.
 
     ``constraint="gp"`` is the WGAN-GP baseline the paper replaces: the
     penalty term is a gradient *of a gradient* through the CDE solve, so it
@@ -109,18 +117,18 @@ def make_sde_gan_step(cfg, g_update, d_update, batch: int, seq_len: int,
         y_real = shard_time_major(ou_process(jax.random.fold_in(k, 0),
                                              batch, seq_len, dtype=cfg.dtype))
 
-        # One shared forward (generator solve + joint solve + CDE solve),
-        # two cotangent pulls — instead of jax.grad per player re-running
-        # the full SDE solves.
-        def both_losses(gen, disc):
+        # One shared forward and one cotangent pull, the critic loss's: it
+        # rests on gan_losses' gen_loss = -mean(fake_score) being minus the
+        # only generator-dependent term of disc_loss (see the docstring).
+        def critic_loss(gen, disc):
             p = {"gen": gen, "disc": disc}
             gl, dl, _ = gan_losses(p, cfg, jax.random.fold_in(k, 1), y_real, batch)
-            return gl, dl
+            return dl, gl
 
-        (gl, dl), vjp = jax.vjp(both_losses, params["gen"], params["disc"])
-        one, zero = jnp.ones_like(gl), jnp.zeros_like(gl)
-        gg, _ = vjp((one, zero))
-        _, dg = vjp((zero, one))
+        dl, vjp, gl = jax.vjp(critic_loss, params["gen"], params["disc"],
+                              has_aux=True)
+        neg_gg, dg = vjp(jnp.ones_like(dl))
+        gg = jax.tree.map(jnp.negative, neg_gg)
 
         upd, d_state = d_update(dg, d_state, params["disc"])
         disc = optim.apply_updates(params["disc"], upd)  # projection folded in

@@ -276,8 +276,9 @@ def train_sde_gan(steps: int, batch: int, ckpt_dir: Optional[str] = None,
     Heun with the exact adjoint by default (``gradient_mode`` is derived
     from the config inside repro.core.sde).  The step itself comes from
     :func:`repro.launch.steps.make_sde_gan_step`: one shared forward per
-    step via ``jax.vjp``, careful clipping as the tail of the discriminator
-    optimiser chain, batch sharded over the data-parallel mesh.
+    step via ``jax.vjp`` and one cotangent pull, careful clipping as the
+    tail of the discriminator optimiser chain, batch sharded over the
+    data-parallel mesh.
     """
     from ..core.losses import signature_mmd
     from ..core.sde import generator_sample

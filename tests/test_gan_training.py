@@ -13,13 +13,16 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import nn, optim
 from repro.core.clipping import (clip_lipschitz, clip_pytree,
                                  lipschitz_bound_mlp, max_lipschitz_bound,
                                  per_layer_violation)
-from repro.core.sde import (NeuralSDEConfig, discriminator_init,
+from repro.core.sde import (NeuralSDEConfig, discriminator_init, gan_losses,
                             generator_init)
+from repro.data.synthetic import ou_process
+from repro.distributed.sharding import shard_time_major
 from repro.launch.steps import make_gan_optimizers, make_sde_gan_step
 
 TINY = dict(num_steps=8)          # 8 solver steps per solve
@@ -145,6 +148,94 @@ def test_clip_step_dispatch_buffer_count(key):
     assert len(jax.tree.leaves(out)) == n_params + 2 * 3 + 3
     assert len(jax.tree.leaves(args)) == n_params + 2 * 3 + 1
     assert len(jax.tree.leaves(out)) <= 37   # 28 parameter leaves, as at ou_gan's size
+
+
+def _losses(cfg, gen, disc, k):
+    """The clip step's ``(gen_loss, disc_loss)`` under the step's key."""
+    y_real = shard_time_major(ou_process(jax.random.fold_in(k, 0), BATCH,
+                                         SEQ, dtype=cfg.dtype))
+    gl, dl, _ = gan_losses({"gen": gen, "disc": disc}, cfg,
+                           jax.random.fold_in(k, 1), y_real, BATCH)
+    return gl, dl
+
+
+def _two_pull_grads(cfg, params, k):
+    """The clip step's gradients as two cotangent pulls, one per player, on
+    ``(gen_loss, disc_loss)``: the oracle for the one-pull step."""
+    (gl, dl), vjp = jax.vjp(lambda g, d: _losses(cfg, g, d, k),
+                            params["gen"], params["disc"])
+    one, zero = jnp.ones_like(gl), jnp.zeros_like(gl)
+    gg, _ = vjp((one, zero))
+    _, dg = vjp((zero, one))
+    return gl, dl, gg, dg
+
+
+def _grads_as_state(grads, state, params):
+    """An "optimiser" that hands the step's gradient back as its state and
+    leaves the parameters where they are."""
+    return jax.tree.map(jnp.zeros_like, grads), grads
+
+
+def _count_scans(jaxpr):
+    return sum((e.primitive.name == "scan")
+               + sum(_count_scans(sub)
+                     for sub in jax.core.jaxprs_in_params(e.params))
+               for e in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_clip_step_one_pull_equals_two_pulls(seed):
+    """The clip step pulls one cotangent, the critic loss's, and negates
+    the generator's part: gan_losses has gen_loss = -mean(fake_score) and
+    disc_loss = mean(fake_score) - mean(real_score), so d gen_loss / d gen
+    = -d disc_loss / d gen.  Both players' gradients equal the two-pull
+    form bitwise, and per-player jax.grad to float32 round-off; the losses
+    are the same.  A change of loss that breaks the identity fails here."""
+    key = jax.random.PRNGKey(seed)
+    cfg, params, _, _, _ = _tiny_setup(key)
+    k = jax.random.fold_in(key, 2)
+    step = jax.jit(make_sde_gan_step(cfg, _grads_as_state, _grads_as_state,
+                                     BATCH, SEQ))
+    out, gg, dg, m = step(params, None, None, k)
+    gl, dl, want_gg, want_dg = jax.jit(
+        lambda p, k: _two_pull_grads(cfg, p, k))(params, k)
+
+    assert float(m["gen_loss"]) == float(gl)
+    assert float(m["disc_loss"]) == float(dl)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for got, want in ((gg, want_gg), (dg, want_dg)):
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    ref_gg = jax.jit(jax.grad(
+        lambda g: _losses(cfg, g, params["disc"], k)[0]))(params["gen"])
+    ref_dg = jax.jit(jax.grad(
+        lambda d: _losses(cfg, params["gen"], d, k)[1]))(params["disc"])
+    for got, ref in ((gg, ref_gg), (dg, ref_dg)):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            a, b = np.asarray(a), np.asarray(b)
+            assert np.linalg.norm(a - b) <= 5e-4 * np.linalg.norm(b)
+
+
+def test_clip_step_runs_each_backward_solve_once(key):
+    """Structural, no clock: the clip step's jaxpr holds one backward scan
+    per forward solve (joint generator/discriminator solve, real-path CDE
+    solve), where pulling one cotangent per player held two."""
+    cfg, params, g_state, d_state, step = _tiny_setup(key)
+    k = jax.random.fold_in(key, 2)
+
+    def scans(fn, *args):
+        return _count_scans(jax.make_jaxpr(fn)(*args).jaxpr)
+
+    n_data = scans(lambda k: ou_process(k, BATCH, SEQ, dtype=cfg.dtype), k)
+    n_solves = scans(lambda p, k: _losses(cfg, p["gen"], p["disc"], k),
+                     params, k) - n_data
+    assert n_solves == 2
+    assert scans(step, params, g_state, d_state, k) - n_data == 2 * n_solves
+    assert scans(lambda p, k: _two_pull_grads(cfg, p, k), params, k) \
+        - n_data == 3 * n_solves
 
 
 def test_gp_step_runs_and_matches_metric_keys(key):
