@@ -15,7 +15,7 @@ paper                  module
                        solve), repro.core.losses (Wasserstein, sig-MMD)
 §3 / Alg. 1–2          repro.core.solvers (reversible Heun + inverse),
                        repro.kernels.reversible_heun_step (fused steps)
-§3 / App. C (adjoint)  repro.core.adjoint (exact O(1)-memory custom VJP;
+§3 / App. C (adjoint)  repro.core.gradients (exact O(1)-memory custom VJP;
                        continuous-adjoint baseline, eq. (6))
 §4 / Alg. 3–4          repro.core.brownian_interval (host Brownian Interval,
                        LRU + search hints), repro.core.brownian

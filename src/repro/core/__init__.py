@@ -1,6 +1,5 @@
 """The paper's primary contributions as composable JAX modules."""
 
-from .adjoint import continuous_adjoint_solve, reversible_heun_solve  # noqa: F401
 from .brownian import (  # noqa: F401
     BrownianPath,
     DenseBrownianPath,
@@ -19,8 +18,10 @@ from .gradients import (  # noqa: F401
     GradientBackend,
     PrecisionPolicy,
     checkpoint_schedule,
+    continuous_adjoint_solve,
     register_backend,
     resolve_precision,
+    reversible_heun_solve,
 )
 from .solve import (  # noqa: F401
     GRADIENT_MODES,

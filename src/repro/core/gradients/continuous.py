@@ -5,9 +5,6 @@ backwards in time alongside the adjoint SDE.  The recomputed ``z`` differs
 from the forward pass by the solver truncation error, so gradients carry
 O(√h) error — the failure mode the paper eliminates, kept here as the
 measured baseline (benchmarks/gradient_error.py charts it).
-
-Moved verbatim from ``repro.core.adjoint`` when the gradient layer became
-backend-structured; only the registry glue at the bottom is new.
 """
 
 from __future__ import annotations

@@ -7,10 +7,9 @@ accumulates local VJPs.  Activation memory is **O(1) in the number of
 steps** (only the terminal state is saved) and the resulting gradients
 match discretise-then-optimise **to floating-point error** (paper Fig. 2).
 
-Moved verbatim from ``repro.core.adjoint`` when the gradient layer became
-backend-structured — the solver code here (including the fused-kernel
-local VJP) is bitwise the pre-refactor implementation; only the module
-path and the thin registry glue at the bottom are new.
+One forward scan (:func:`_scan_forward`) and one backward sweep serve the
+trajectory and terminal forms; one reverse-and-pull step
+(:func:`_reverse_and_pull`) serves that sweep and the adaptive replay.
 """
 
 from __future__ import annotations
@@ -27,7 +26,7 @@ from .. import scopes
 from ..brownian import BrownianPath
 from ..solvers import (
     RevHeunState,
-    apply_diffusion,
+    reversible_heun_reverse_at,
     reversible_heun_reverse_step,
     reversible_heun_step,
 )
@@ -102,12 +101,151 @@ def _fused_local_vjp(drift, diffusion, params, state0, cts, t_left, dt, dw):
     return dparams, (d_z, d_zh, d_mu, d_sigma)
 
 
+def _reverse_and_pull(drift, diffusion, params, noise, use_pallas):
+    """One backward step: ``(state1, cts, t_left, dt, dw) -> (state0, cts0,
+    dparams)``.
+
+    Reconstructs the step's left state (Algorithm 2, anchored on the
+    ``t_left`` the caller passes), then pulls the step-``n+1`` cotangents
+    ``cts = (g_z, g_zh, g_mu, g_sigma)`` back through the local forward:
+    the hand-derived :func:`_fused_local_vjp` when fused (``use_pallas``
+    with diagonal noise), else ``jax.vjp`` of the unfused stepper (1 NFE).
+    The fixed-grid sweep and the adaptive replay both step through this.
+    """
+    fused = use_pallas and noise == "diagonal"
+
+    def pull(state1, cts, t_left, dt, dw):
+        state0 = reversible_heun_reverse_at(state1, t_left, dt, dw, drift,
+                                            diffusion, params, noise,
+                                            use_pallas=fused)
+        if fused:
+            dparams, cts0 = _fused_local_vjp(drift, diffusion, params, state0,
+                                             cts, t_left, dt, dw)
+            return state0, cts0, dparams
+
+        def local_forward(p, z, zh, mu, sigma):
+            """Algorithm 1 as a pure function of the carried state."""
+            return tuple(reversible_heun_step(
+                RevHeunState(z, zh, mu, sigma), t_left, dt, dw, drift,
+                diffusion, p, noise))
+
+        _, vjp = jax.vjp(local_forward, params, *state0)
+        dparams, *cts0 = vjp(cts)
+        return state0, tuple(cts0), dparams
+
+    return pull
+
+
+def _zero_carry(final, g_zT, params):
+    """Backward carry at the terminal state: only ``g_z`` is seeded."""
+    g_params0 = jax.tree.map(jnp.zeros_like, params)
+    zeros = jnp.zeros_like(final.z)
+    return (final, (g_zT, zeros, zeros, jnp.zeros_like(final.sigma)),
+            g_params0)
+
+
+def _pull_initial(drift, diffusion, params, t0, z0, cts, g_params):
+    """Close the sweep at ``t0``: ``ẑ₀ = z₀``, ``μ₀ = drift(params, t0,
+    z₀)``, ``σ₀ = diffusion(params, t0, z₀)``.  -> ``(g_params, g_z0)``."""
+
+    def init_fn(params_, z0_):
+        return z0_, z0_, drift(params_, t0, z0_), diffusion(params_, t0, z0_)
+
+    _, vjp0 = jax.vjp(init_fn, params, z0)
+    dparams0, g_z0 = vjp0(cts)
+    return jax.tree.map(jnp.add, g_params, dparams0), g_z0
+
+
 # =============================================================================
 # Reversible Heun with exact O(1)-memory adjoint
 # =============================================================================
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 5, 6, 7, 8, 9))
+@scopes.scoped(scopes.SOLVE)
+def _scan_forward(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
+                  use_pallas, save_trajectory):
+    """Algorithm 1 over the fixed grid -> ``(traj or None, final state)``.
+
+    ``traj`` is ``(num_steps+1, *z0.shape)`` (index 0 is ``z0``) when
+    ``save_trajectory``; otherwise nothing O(num_steps) is built.
+    """
+    dt = (t1 - t0) / num_steps
+    dtype = z0.dtype
+    state0 = RevHeunState(z0, z0, drift(params, t0, z0), diffusion(params, t0, z0))
+    gen = _gen_spec(bm, z0, noise, use_pallas)
+
+    def body(state, n):
+        t = t0 + n * dt
+        if gen is not None:
+            # ΔW generated inside the fused phase-1 kernel (bitwise
+            # bm.increment(n, num_steps)); no host-side draw per step.
+            key, dt_grid_fn = gen
+            new = reversible_heun_step(state, t, dt, None, drift, diffusion,
+                                       params, noise, use_pallas=use_pallas,
+                                       gen=(key, n, dt_grid_fn(num_steps)))
+        else:
+            dw = bm.increment(n, num_steps).astype(dtype)
+            new = reversible_heun_step(state, t, dt, dw, drift, diffusion, params, noise,
+                                       use_pallas=use_pallas)
+        return new, (new.z if save_trajectory else None)
+
+    final, zs = lax.scan(body, state0, jnp.arange(num_steps))
+    if not save_trajectory:
+        return None, final
+    return jnp.concatenate([z0[None], zs], axis=0), final
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 5, 6, 7, 8, 9, 10))
+def _exact_solve(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
+                 use_pallas, save_trajectory):
+    traj, final = _scan_forward(drift, diffusion, params, z0, bm, t0, t1,
+                                num_steps, noise, use_pallas, save_trajectory)
+    return traj if save_trajectory else final.z
+
+
+def _fwd_rule(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
+              use_pallas, save_trajectory):
+    traj, final = _scan_forward(drift, diffusion, params, z0, bm, t0, t1,
+                                num_steps, noise, use_pallas, save_trajectory)
+    # O(1)-in-depth residuals: terminal solver state only (+ params, bm key).
+    return (traj if save_trajectory else final.z), (params, final, bm)
+
+
+@scopes.scoped(scopes.ADJOINT)
+def _bwd_rule(drift, diffusion, t0, t1, num_steps, noise, use_pallas,
+              save_trajectory, residuals, g_out):
+    """The fixed-grid backward sweep, right to left.  ``g_out`` is the
+    trajectory's cotangent (each step's slice is injected into ``g_z`` as
+    the sweep passes it) or, for the terminal form, ``z_N``'s."""
+    params, final, bm = residuals
+    dt = (t1 - t0) / num_steps
+    dtype = final.z.dtype
+    g_traj = g_out if save_trajectory else None
+    g_zT = g_out[num_steps] if save_trajectory else g_out
+    pull = _reverse_and_pull(drift, diffusion, params, noise, use_pallas)
+
+    def body(carry, n):
+        state1, cts, g_params = carry
+        t1_local = t0 + (n + 1) * dt
+        dw = bm.increment(n, num_steps).astype(dtype)
+        state0, (d_z, d_zh, d_mu, d_sigma), dparams = pull(
+            state1, cts, t1_local - dt, dt, dw)
+        g_params = jax.tree.map(jnp.add, g_params, dparams)
+        if g_traj is not None:
+            d_z = d_z + g_traj[n]
+        return (state0, (d_z, d_zh, d_mu, d_sigma), g_params), None
+
+    (state0, cts, g_params), _ = lax.scan(
+        body, _zero_carry(final, g_zT, params),
+        jnp.arange(num_steps - 1, -1, -1))
+    g_params, g_z0 = _pull_initial(drift, diffusion, params, t0, state0.z,
+                                   cts, g_params)
+    return (g_params, g_z0, _float0_zeros(bm))
+
+
+_exact_solve.defvjp(_fwd_rule, _bwd_rule)
+
+
 def reversible_heun_solve(
     drift: Callable,
     diffusion: Callable,
@@ -132,121 +270,12 @@ def reversible_heun_solve(
     closed-form state reconstruction, and the hand-derived per-step
     cotangent phases (:func:`_fused_local_vjp`, bitwise the unfused
     ``jax.vjp``).  AD never traces through a Pallas op — the backward
-    kernels ARE the derivative, registered through this ``custom_vjp``.
+    kernels ARE the derivative, registered through the solve's ``custom_vjp``.
     """
-    traj, _final = _forward(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
-                            use_pallas)
-    return traj
+    return _exact_solve(drift, diffusion, params, z0, bm, t0, t1, num_steps,
+                        noise, use_pallas, True)
 
 
-@scopes.scoped(scopes.SOLVE)
-def _forward(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
-             use_pallas=False):
-    dt = (t1 - t0) / num_steps
-    dtype = z0.dtype
-    state0 = RevHeunState(z0, z0, drift(params, t0, z0), diffusion(params, t0, z0))
-    gen = _gen_spec(bm, z0, noise, use_pallas)
-
-    def body(state, n):
-        t = t0 + n * dt
-        if gen is not None:
-            # ΔW generated inside the fused phase-1 kernel (bitwise
-            # bm.increment(n, num_steps)); no host-side draw per step.
-            key, dt_grid_fn = gen
-            new = reversible_heun_step(state, t, dt, None, drift, diffusion,
-                                       params, noise, use_pallas=use_pallas,
-                                       gen=(key, n, dt_grid_fn(num_steps)))
-        else:
-            dw = bm.increment(n, num_steps).astype(dtype)
-            new = reversible_heun_step(state, t, dt, dw, drift, diffusion, params, noise,
-                                       use_pallas=use_pallas)
-        return new, new.z
-
-    final, zs = lax.scan(body, state0, jnp.arange(num_steps))
-    traj = jnp.concatenate([z0[None], zs], axis=0)
-    return traj, final
-
-
-def _fwd_rule(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise, use_pallas):
-    traj, final = _forward(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
-                           use_pallas)
-    # O(1)-in-depth residuals: terminal solver state only (+ params, bm key).
-    return traj, (params, final, bm)
-
-
-@scopes.scoped(scopes.ADJOINT)
-def _bwd_rule(drift, diffusion, t0, t1, num_steps, noise, use_pallas, residuals, g_traj):
-    params, final, bm = residuals
-    dt = (t1 - t0) / num_steps
-    dtype = final.z.dtype
-
-    def local_forward(params_, z, zh, mu, sigma, t, dw):
-        """Algorithm 1 as a pure function of the carried state (1 NFE)."""
-        return tuple(
-            reversible_heun_step(
-                RevHeunState(z, zh, mu, sigma), t, dt, dw, drift, diffusion, params_, noise
-            )
-        )
-
-    g_params0 = jax.tree.map(jnp.zeros_like, params)
-    zeros = jnp.zeros_like(final.z)
-    zeros_sig = jnp.zeros_like(final.sigma)
-    # cotangents: (g_z, g_zh, g_mu, g_sigma); seed g_z with the terminal
-    # trajectory cotangent.
-    carry0 = (final, (g_traj[num_steps], zeros, zeros, zeros_sig), g_params0)
-
-    fused = use_pallas and noise == "diagonal"
-
-    def body(carry, n):
-        state1, (g_z, g_zh, g_mu, g_sigma), g_params = carry
-        t1_local = t0 + (n + 1) * dt
-        dw = bm.increment(n, num_steps).astype(dtype)
-        # ---- reverse step: closed-form state reconstruction (Algorithm 2)
-        state0 = reversible_heun_reverse_step(
-            state1, t1_local, dt, dw, drift, diffusion, params, noise,
-            use_pallas=use_pallas,
-        )
-        # ---- local forward + local backward
-        if fused:
-            # hand-derived transpose through the backward kernels — one
-            # field VJP, elementwise cotangent phases fused (bitwise the
-            # unfused jax.vjp below)
-            dparams, (d_z, d_zh, d_mu, d_sigma) = _fused_local_vjp(
-                drift, diffusion, params, state0,
-                (g_z, g_zh, g_mu, g_sigma), t1_local - dt, dt, dw)
-        else:
-            _, vjp = jax.vjp(
-                lambda p, z, zh, mu, sigma: local_forward(p, z, zh, mu, sigma, t1_local - dt, dw),
-                params,
-                state0.z,
-                state0.zh,
-                state0.mu,
-                state0.sigma,
-            )
-            dparams, d_z, d_zh, d_mu, d_sigma = vjp((g_z, g_zh, g_mu, g_sigma))
-        g_params = jax.tree.map(jnp.add, g_params, dparams)
-        # inject this step's trajectory cotangent into g_z
-        d_z = d_z + g_traj[n]
-        return (state0, (d_z, d_zh, d_mu, d_sigma), g_params), None
-
-    (state0, (g_z, g_zh, g_mu, g_sigma), g_params), _ = lax.scan(
-        body, carry0, jnp.arange(num_steps - 1, -1, -1)
-    )
-
-    # ---- initial condition: zh_0 = z_0, mu_0 = drift(params, t0, z0), ...
-    def init_fn(params_, z0_):
-        return z0_, z0_, drift(params_, t0, z0_), diffusion(params_, t0, z0_)
-
-    _, vjp0 = jax.vjp(init_fn, params, state0.z)
-    dparams0, g_z0 = vjp0((g_z, g_zh, g_mu, g_sigma))
-    g_params = jax.tree.map(jnp.add, g_params, dparams0)
-    return (g_params, g_z0, _float0_zeros(bm))
-
-
-reversible_heun_solve.defvjp(_fwd_rule, _bwd_rule)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 5, 6, 7, 8, 9))
 def reversible_heun_solve_final(
     drift: Callable,
     diffusion: Callable,
@@ -266,80 +295,8 @@ def reversible_heun_solve_final(
     reversible *residual-stack* wrapper (models/reversible.py) uses: there
     ``num_steps`` is the network depth and the saving is activation memory.
     """
-    _traj, final = _forward(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
-                            use_pallas)
-    return final.z
-
-
-@scopes.scoped(scopes.SOLVE)
-def _fwd_rule_final(drift, diffusion, params, z0, bm, t0, t1, num_steps, noise, use_pallas):
-    dt = (t1 - t0) / num_steps
-    dtype = z0.dtype
-    state0 = RevHeunState(z0, z0, drift(params, t0, z0), diffusion(params, t0, z0))
-    gen = _gen_spec(bm, z0, noise, use_pallas)
-
-    def body(state, n):
-        t = t0 + n * dt
-        if gen is not None:
-            key, dt_grid_fn = gen
-            return reversible_heun_step(state, t, dt, None, drift, diffusion,
-                                        params, noise, use_pallas=use_pallas,
-                                        gen=(key, n, dt_grid_fn(num_steps))), None
-        dw = bm.increment(n, num_steps).astype(dtype)
-        return reversible_heun_step(state, t, dt, dw, drift, diffusion, params, noise,
-                                    use_pallas=use_pallas), None
-
-    final, _ = lax.scan(body, state0, jnp.arange(num_steps))
-    return final.z, (params, final, bm)
-
-
-@scopes.scoped(scopes.ADJOINT)
-def _bwd_rule_final(drift, diffusion, t0, t1, num_steps, noise, use_pallas, residuals, g_zT):
-    params, final, bm = residuals
-    dt = (t1 - t0) / num_steps
-    dtype = final.z.dtype
-
-    def local_forward(params_, z, zh, mu, sigma, t, dw):
-        return tuple(reversible_heun_step(
-            RevHeunState(z, zh, mu, sigma), t, dt, dw, drift, diffusion, params_, noise))
-
-    g_params0 = jax.tree.map(jnp.zeros_like, params)
-    zeros = jnp.zeros_like(final.z)
-    carry0 = (final, (g_zT, zeros, zeros, jnp.zeros_like(final.sigma)), g_params0)
-
-    fused = use_pallas and noise == "diagonal"
-
-    def body(carry, n):
-        state1, cts, g_params = carry
-        t1_local = t0 + (n + 1) * dt
-        dw = bm.increment(n, num_steps).astype(dtype)
-        state0 = reversible_heun_reverse_step(
-            state1, t1_local, dt, dw, drift, diffusion, params, noise,
-            use_pallas=use_pallas)
-        if fused:
-            dparams, (d_z, d_zh, d_mu, d_sigma) = _fused_local_vjp(
-                drift, diffusion, params, state0, cts, t1_local - dt, dt, dw)
-        else:
-            _, vjp = jax.vjp(
-                lambda p, z, zh, mu, sigma: local_forward(p, z, zh, mu, sigma, t1_local - dt, dw),
-                params, state0.z, state0.zh, state0.mu, state0.sigma)
-            dparams, d_z, d_zh, d_mu, d_sigma = vjp(cts)
-        g_params = jax.tree.map(jnp.add, g_params, dparams)
-        return (state0, (d_z, d_zh, d_mu, d_sigma), g_params), None
-
-    (state0, (g_z, g_zh, g_mu, g_sigma), g_params), _ = lax.scan(
-        body, carry0, jnp.arange(num_steps - 1, -1, -1))
-
-    def init_fn(params_, z0_):
-        return z0_, z0_, drift(params_, t0, z0_), diffusion(params_, t0, z0_)
-
-    _, vjp0 = jax.vjp(init_fn, params, state0.z)
-    dparams0, g_z0 = vjp0((g_z, g_zh, g_mu, g_sigma))
-    g_params = jax.tree.map(jnp.add, g_params, dparams0)
-    return (g_params, g_z0, _float0_zeros(bm))
-
-
-reversible_heun_solve_final.defvjp(_fwd_rule_final, _bwd_rule_final)
+    return _exact_solve(drift, diffusion, params, z0, bm, t0, t1, num_steps,
+                        noise, use_pallas, False)
 
 
 # =============================================================================
@@ -429,18 +386,8 @@ def _bwd_rule_adaptive(drift, diffusion, t0, t1, max_steps, dt0, noise,
     g_zT, _g_converged = g_out  # bool output: float0 cotangent, discarded
     params, final, bm, dts, ts, n_acc, rtol, atol = residuals
     dtype = final.z.dtype
-    fused = use_pallas and noise == "diagonal"
     dkw = {} if bridge_depth is None else {"depth": bridge_depth}
-
-    def local_forward(params_, z, zh, mu, sigma, t, dt, dw):
-        return tuple(reversible_heun_step(
-            RevHeunState(z, zh, mu, sigma), t, dt, dw, drift, diffusion,
-            params_, noise))
-
-    g_params0 = jax.tree.map(jnp.zeros_like, params)
-    zeros = jnp.zeros_like(final.z)
-    carry0 = (final, (g_zT, zeros, zeros, jnp.zeros_like(final.sigma)),
-              g_params0)
+    pull = _reverse_and_pull(drift, diffusion, params, noise, use_pallas)
 
     def body(loop_carry):
         i, carry = loop_carry
@@ -462,36 +409,11 @@ def _bwd_rule_adaptive(drift, diffusion, t0, t1, max_steps, dt0, noise,
                       - bm.value(t_left, **dkw).astype(dtype))
             else:
                 dw = bm.evaluate(t_left, t_left + dt, **dkw).astype(dtype)
-            # Algorithm 2 inline, anchored on the STORED left endpoint so
-            # the vector fields are evaluated at bit-identical times (the
-            # helper's ``t1 - dt`` would reintroduce fp drift).
-            z1, zh1, mu1, sigma1 = state1
-            if fused:
-                from ...kernels import ops
-                zh = ops.rev_heun_phase1(z1, zh1, mu1, sigma1, dw, dt,
-                                         sign=-1.0)
-                mu = drift(params, t_left, zh)
-                sigma = diffusion(params, t_left, zh)
-                z = ops.rev_heun_phase2(z1, mu, mu1, sigma, sigma1, dw, dt,
-                                        sign=-1.0)
-                state0 = RevHeunState(z, zh, mu, sigma)
-                dparams, (d_z, d_zh, d_mu, d_sigma) = _fused_local_vjp(
-                    drift, diffusion, params, state0, cts, t_left, dt, dw)
-            else:
-                zh = (2.0 * z1 - zh1 - mu1 * dt
-                      - apply_diffusion(sigma1, dw, noise))
-                mu = drift(params, t_left, zh)
-                sigma = diffusion(params, t_left, zh)
-                z = z1 - 0.5 * (mu + mu1) * dt - apply_diffusion(
-                    0.5 * (sigma + sigma1), dw, noise)
-                state0 = RevHeunState(z, zh, mu, sigma)
-                _, vjp = jax.vjp(
-                    lambda p, z_, zh_, mu_, sigma_: local_forward(
-                        p, z_, zh_, mu_, sigma_, t_left, dt, dw),
-                    params, state0.z, state0.zh, state0.mu, state0.sigma)
-                dparams, d_z, d_zh, d_mu, d_sigma = vjp(cts)
-            g_params = jax.tree.map(jnp.add, g_params, dparams)
-            return (state0, (d_z, d_zh, d_mu, d_sigma), g_params)
+            # anchored on the STORED left endpoint so the vector fields are
+            # evaluated at bit-identical times (``t_right - dt`` would
+            # reintroduce fp drift)
+            state0, cts0, dparams = pull(state1, cts, t_left, dt, dw)
+            return (state0, cts0, jax.tree.map(jnp.add, g_params, dparams))
 
         return (i - 1, lax.cond(i >= 0, replay, lambda c: c, carry))
 
@@ -500,14 +422,9 @@ def _bwd_rule_adaptive(drift, diffusion, t0, t1, max_steps, dt0, noise,
     # instead of paying the full padded buffer per trajectory (cond lowers
     # to select there, so padded slots would otherwise do real work)
     _, (state0, cts, g_params) = lax.while_loop(
-        lambda c: c[0] >= 0, body, (n_acc - 1, carry0))
-
-    def init_fn(params_, z0_):
-        return z0_, z0_, drift(params_, t0, z0_), diffusion(params_, t0, z0_)
-
-    _, vjp0 = jax.vjp(init_fn, params, state0.z)
-    dparams0, g_z0 = vjp0(cts)
-    g_params = jax.tree.map(jnp.add, g_params, dparams0)
+        lambda c: c[0] >= 0, body, (n_acc - 1, _zero_carry(final, g_zT, params)))
+    g_params, g_z0 = _pull_initial(drift, diffusion, params, t0, state0.z,
+                                   cts, g_params)
     return (g_params, g_z0, _float0_zeros(bm),
             jnp.zeros_like(rtol), jnp.zeros_like(atol))
 
@@ -532,13 +449,8 @@ def _validate(spec, *, noise, save_trajectory, use_pallas, adaptive):
 
 def _solve(spec, drift, diffusion, params, z0, bm, t0, t1, num_steps, *,
            noise, save_trajectory, use_pallas):
-    if save_trajectory:
-        return reversible_heun_solve(
-            drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
-            use_pallas)
-    return reversible_heun_solve_final(
-        drift, diffusion, params, z0, bm, t0, t1, num_steps, noise,
-        use_pallas)
+    return _exact_solve(drift, diffusion, params, z0, bm, t0, t1, num_steps,
+                        noise, use_pallas, save_trajectory)
 
 
 def _solve_adaptive(spec, drift, diffusion, params, z0, bm, rtol, atol,

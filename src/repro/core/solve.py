@@ -349,7 +349,7 @@ def _adaptive_loop(spec, drift, diffusion, params, z0, bm, t0, t1,
 
     Returns ``(final_carry, AdaptiveStats)``.  The accepted ``(ts, dts)``
     scalars are the replay contract consumed by the exact adjoint
-    (repro.core.adjoint): the backward pass re-derives every accepted
+    (repro.core.gradients.reversible): the backward pass re-derives every accepted
     step's ``(t, dt, dw)`` bit-identically from them.
     """
     dtype = z0.dtype

@@ -27,16 +27,18 @@ srk                Itô           5 / step               strong order **1.5**;
 `reversible_heun` here is the *plain scan* version: differentiating through
 it with standard JAX AD gives discretise-then-optimise gradients (and O(N)
 activation memory).  The O(1)-memory exact adjoint lives in
-:mod:`repro.core.adjoint`.
+:mod:`repro.core.gradients.reversible`.
 
 The reversible-Heun hot loop optionally runs through the fused Pallas
 kernels (:mod:`repro.kernels.reversible_heun_step`) via
 ``use_pallas=True`` — see the kernel module docstring for the contract
 (diagonal noise; ``dt`` may be traced, so this includes the adaptive
 driver; plain AD must not trace through the fused ops — gradients use the
-hand-derived backward kernels via :mod:`repro.core.adjoint`).  Callers
-should normally go through the :func:`repro.core.solve.solve` front-end,
-which validates the flag against the solver registry.
+hand-derived backward kernels via :mod:`repro.core.gradients.reversible`).
+Which implementation a fused op runs (Pallas kernel or jnp oracle) is
+decided by :mod:`repro.kernels.ops` alone.  Callers should normally go
+through the :func:`repro.core.solve.solve` front-end, which validates the
+flag against the solver registry.
 """
 
 from __future__ import annotations
@@ -87,23 +89,6 @@ def dw_shape(z_shape, w_dim: Optional[int], noise: str):
     return tuple(z_shape[:-1]) + (w_dim,)
 
 
-def _pallas_dispatch(interpret: Optional[bool]) -> tuple:
-    """Resolve the fused-step implementation -> ``(run_kernel, interpret)``.
-
-    The kernels/ops.py policy (DESIGN.md §5), applied to the solver hot
-    loop: on TPU the compiled Pallas kernels run natively; on CPU/GPU the
-    fused pure-jnp oracle (:mod:`repro.kernels.ref`) runs instead — same
-    math, and XLA fuses it, so ``use_pallas_kernels=True`` never *slows* a
-    non-TPU backend down the way always-interpret mode did.  Passing
-    ``interpret=True`` explicitly forces the Pallas interpreter off-TPU —
-    that is the kernel-equivalence code path the tests pin.
-    """
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        return on_tpu, False
-    return True, interpret and not on_tpu
-
-
 class RevHeunState(NamedTuple):
     """Carried state of the reversible Heun method (Algorithm 1)."""
 
@@ -114,8 +99,7 @@ class RevHeunState(NamedTuple):
 
 
 def reversible_heun_step(state: RevHeunState, t, dt, dw, drift, diffusion, params, noise,
-                         use_pallas: bool = False, interpret: Optional[bool] = None,
-                         gen=None):
+                         use_pallas: bool = False, gen=None):
     """One step of Algorithm 1.  Exactly one drift+diffusion evaluation.
 
     With ``use_pallas=True`` (diagonal noise) the two elementwise state
@@ -123,7 +107,7 @@ def reversible_heun_step(state: RevHeunState, t, dt, dw, drift, diffusion, param
     kernels take it as a scalar operand), so the adaptive driver's
     controller-chosen step sizes work fused too.  AD must not trace through
     this path — gradients go through the hand-derived backward kernels via
-    :mod:`repro.core.adjoint`.
+    :mod:`repro.core.gradients.reversible`.
 
     ``gen=(key, n, dt_grid)`` generates this step's ``ΔW`` *inside* the
     phase-1 kernel (counter-based Threefry keyed on ``n``, bitwise
@@ -134,22 +118,17 @@ def reversible_heun_step(state: RevHeunState, t, dt, dw, drift, diffusion, param
     """
     z, zh, mu, sigma = state
     if use_pallas and noise == "diagonal":
-        run_kernel, interp = _pallas_dispatch(interpret)
         from ..kernels import ops
 
-        use_kernel = True if run_kernel and interp else (run_kernel or None)
         if gen is not None:
             key, n, dt_grid = gen
             zh1, dw = ops.rev_heun_phase1_gen(z, zh, mu, sigma, key, n,
-                                              dt_grid, dt,
-                                              use_kernel=use_kernel)
+                                              dt_grid, dt)
         else:
-            zh1 = ops.rev_heun_phase1(z, zh, mu, sigma, dw, dt,
-                                      use_kernel=use_kernel)
+            zh1 = ops.rev_heun_phase1(z, zh, mu, sigma, dw, dt)
         mu1 = drift(params, t + dt, zh1)
         sigma1 = diffusion(params, t + dt, zh1)
-        z1 = ops.rev_heun_phase2(z, mu, mu1, sigma, sigma1, dw, dt,
-                                 use_kernel=use_kernel)
+        z1 = ops.rev_heun_phase2(z, mu, mu1, sigma, sigma1, dw, dt)
         return RevHeunState(z1, zh1, mu1, sigma1)
     zh1 = 2.0 * z - zh + mu * dt + apply_diffusion(sigma, dw, noise)
     mu1 = drift(params, t + dt, zh1)
@@ -158,32 +137,42 @@ def reversible_heun_step(state: RevHeunState, t, dt, dw, drift, diffusion, param
     return RevHeunState(z1, zh1, mu1, sigma1)
 
 
-def reversible_heun_reverse_step(state: RevHeunState, t1, dt, dw, drift, diffusion, params, noise,
-                                 use_pallas: bool = False, interpret: Optional[bool] = None):
-    """Algebraic inverse of :func:`reversible_heun_step` (Algorithm 2, reverse).
+def reversible_heun_reverse_at(state: RevHeunState, t_left, dt, dw, drift, diffusion,
+                               params, noise, use_pallas: bool = False):
+    """Algorithm 2 anchored on the step's left time ``t_left``.
 
     Reconstructs ``(z_n, ẑ_n, μ_n, σ_n)`` from ``(z_{n+1}, ẑ_{n+1}, μ_{n+1},
-    σ_{n+1})`` in closed form — the paper's key property.  ``use_pallas``
-    runs the same fused kernels with ``sign=-1`` (backward reconstruction).
+    σ_{n+1})`` in closed form — the paper's key property — evaluating the
+    vector fields at ``t_left`` exactly as the caller passes it: the
+    adaptive replay passes the stored left endpoint, so the fields see the
+    bit-identical time the forward step saw.  ``use_pallas`` runs the same
+    fused kernels as the forward step with ``sign=-1``.
     """
     z1, zh1, mu1, sigma1 = state
     if use_pallas and noise == "diagonal":
-        run_kernel, interp = _pallas_dispatch(interpret)
         from ..kernels import ops
 
-        use_kernel = True if run_kernel and interp else (run_kernel or None)
-        zh = ops.rev_heun_phase1(z1, zh1, mu1, sigma1, dw, dt, sign=-1.0,
-                                 use_kernel=use_kernel)
-        mu = drift(params, t1 - dt, zh)
-        sigma = diffusion(params, t1 - dt, zh)
-        z = ops.rev_heun_phase2(z1, mu, mu1, sigma, sigma1, dw, dt, sign=-1.0,
-                                use_kernel=use_kernel)
+        zh = ops.rev_heun_phase1(z1, zh1, mu1, sigma1, dw, dt, sign=-1.0)
+        mu = drift(params, t_left, zh)
+        sigma = diffusion(params, t_left, zh)
+        z = ops.rev_heun_phase2(z1, mu, mu1, sigma, sigma1, dw, dt, sign=-1.0)
         return RevHeunState(z, zh, mu, sigma)
     zh = 2.0 * z1 - zh1 - mu1 * dt - apply_diffusion(sigma1, dw, noise)
-    mu = drift(params, t1 - dt, zh)
-    sigma = diffusion(params, t1 - dt, zh)
+    mu = drift(params, t_left, zh)
+    sigma = diffusion(params, t_left, zh)
     z = z1 - 0.5 * (mu + mu1) * dt - apply_diffusion(0.5 * (sigma + sigma1), dw, noise)
     return RevHeunState(z, zh, mu, sigma)
+
+
+def reversible_heun_reverse_step(state: RevHeunState, t1, dt, dw, drift, diffusion, params, noise,
+                                 use_pallas: bool = False):
+    """Algebraic inverse of :func:`reversible_heun_step` (Algorithm 2, reverse).
+
+    ``t1`` is the step's right time; :func:`reversible_heun_reverse_at`
+    with ``t_left = t1 - dt``.
+    """
+    return reversible_heun_reverse_at(state, t1 - dt, dt, dw, drift, diffusion,
+                                      params, noise, use_pallas=use_pallas)
 
 
 # -----------------------------------------------------------------------------
@@ -212,10 +201,9 @@ def reversible_heun_reverse_step(state: RevHeunState, t1, dt, dw, drift, diffusi
 
 
 def reversible_heun_embedded_step(state: RevHeunState, t, dt, dw, drift, diffusion,
-                                  params, noise, use_pallas: bool = False,
-                                  interpret: Optional[bool] = None):
+                                  params, noise, use_pallas: bool = False):
     new = reversible_heun_step(state, t, dt, dw, drift, diffusion, params, noise,
-                               use_pallas=use_pallas, interpret=interpret)
+                               use_pallas=use_pallas)
     return new, (new.z - new.zh) + (state.z - state.zh)
 
 
@@ -351,7 +339,7 @@ def sde_solve(
     Returns the trajectory ``(num_steps+1, *z0.shape)`` if ``save_trajectory``
     else the terminal value.  Differentiating through this function gives
     discretise-then-optimise gradients (O(N) memory).  For the paper's O(1)
-    exact adjoint use :func:`repro.core.adjoint.reversible_heun_solve`.
+    exact adjoint use :func:`repro.core.gradients.reversible_heun_solve`.
 
     ``use_pallas_kernels`` fuses the reversible-Heun state updates
     (diagonal noise only).  The fused ops have no VJP rule, so this flag is

@@ -10,8 +10,8 @@ Phase 1 computes ẑ_{n+1} (before the vector-field evaluation); phase 2
 computes z_{n+1} (after).  Both take a static ``sign``: ``+1.0`` is the
 forward step (Algorithm 1) and ``-1.0`` the algebraic inverse (Algorithm 2,
 used by the O(1)-memory backward reconstruction in
-:mod:`repro.core.adjoint`), which negates the Δt and ΔW terms in-kernel so
-no extra negated operand ever touches HBM.
+:mod:`repro.core.gradients.reversible`), which negates the Δt and ΔW terms
+in-kernel so no extra negated operand ever touches HBM.
 
 The backward (cotangent) phases are the hand-derived transpose of one
 Algorithm-1 step, factored around the single vector-field VJP exactly as
@@ -19,8 +19,8 @@ DESIGN.md §3 derives it: :func:`rev_heun_bwd_phase1` builds the seeds of
 the field VJP, :func:`rev_heun_bwd_phase2` distributes its result onto the
 step-``n`` state cotangents.  Their op order is chosen so every output is
 BITWISE what ``jax.vjp`` of the unfused stepper produces — the fused exact
-adjoint in :mod:`repro.core.adjoint` rests on that identity, and
-tests/test_kernel_parity.py pins it.
+adjoint in :mod:`repro.core.gradients.reversible` rests on that identity,
+and tests/test_kernel_parity.py pins it.
 
 Kernel contract
 ===============
@@ -44,12 +44,13 @@ Kernel contract
   (tests/test_kernel_parity.py, tests/test_solve.py).  The default is the
   compiled kernel.  Off-TPU the solver hot loop runs the jnp oracle in
   :mod:`repro.kernels.ref` (the kernels/ops.py policy) and reaches the
-  interpreter only when a caller asks for it explicitly.
+  interpreter only through ``ops.*(use_kernel=True)`` or
+  ``REPRO_FORCE_PALLAS_INTERPRET=1``.
 * **Differentiability**: ``pallas_call`` still has no *automatic* VJP rule
   — but it no longer needs one: the backward phases above ARE the
   derivative, registered through the solver-level ``custom_vjp`` in
-  :mod:`repro.core.adjoint`.  AD never traces through a kernel; the
-  adjoint rules call the backward kernels directly.  ``jax.vmap``
+  :mod:`repro.core.gradients.reversible`.  AD never traces through a
+  kernel; the adjoint rules call the backward kernels directly.  ``jax.vmap``
   (batched multi-trajectory solving) IS supported.
 """
 

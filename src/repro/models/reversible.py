@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from ..core.adjoint import reversible_heun_solve_final
+from ..core.gradients import reversible_heun_solve_final
 
 
 @jax.tree_util.register_pytree_node_class

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.adjoint import continuous_adjoint_solve, reversible_heun_solve
+from repro.core.gradients import continuous_adjoint_solve, reversible_heun_solve
 from repro.core.brownian import BrownianPath
 from repro.core.solvers import sde_solve
 

@@ -167,6 +167,32 @@ def test_reversible_adjoint_dispatch_bitwise_final(key):
     _grads_equal(g1, g2)
 
 
+@pytest.mark.parametrize("noise,use_pallas", [
+    ("diagonal", False), ("diagonal", True),
+    ("general", False), ("general", True)])
+def test_trajectory_and_terminal_forms_share_the_sweep(key, noise,
+                                                       use_pallas):
+    """The trajectory form under a loss that reads only ``traj[-1]`` is the
+    terminal form, bit for bit: one forward scan and one backward sweep
+    serve both (general noise with ``use_pallas`` runs unfused)."""
+    params, drift, diffusion, z0, bm = _problem(key, noise=noise)
+
+    def via_traj(p, z):
+        traj = reversible_heun_solve(drift, diffusion, p, z, bm, 0.0, 1.0, 8,
+                                     noise, use_pallas)
+        return jnp.sum(traj[-1] ** 2)
+
+    def via_final(p, z):
+        zT = reversible_heun_solve_final(drift, diffusion, p, z, bm, 0.0,
+                                         1.0, 8, noise, use_pallas)
+        return jnp.sum(zT ** 2)
+
+    l1, g1 = jax.value_and_grad(via_traj, argnums=(0, 1))(params, z0)
+    l2, g2 = jax.value_and_grad(via_final, argnums=(0, 1))(params, z0)
+    np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
+    _grads_equal(g1, g2)
+
+
 def test_reversible_adjoint_dispatch_bitwise_adaptive(key):
     params, drift, diffusion, z0, bm = _problem(key)
     kw = dict(rtol=1e-2, atol=1e-4, max_steps=64, dt0=1.0 / 8)

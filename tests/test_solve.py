@@ -366,11 +366,11 @@ def test_pallas_fused_under_jit_and_vmap(key):
                                rtol=1e-6, atol=1e-6)
 
 
-def test_fused_step_dispatch_interpret_matches_oracle(key):
-    """The fused-step dispatcher (DESIGN.md §5): off-TPU the auto path runs
-    the fused jnp oracle, and ``interpret=True`` forces the Pallas
-    interpreter — both must agree with the plain unfused stepper, for the
-    forward step AND the sign=-1 reverse reconstruction."""
+def test_fused_step_dispatch_interpret_matches_oracle(key, monkeypatch):
+    """The fused-step dispatch (kernels/ops.py, DESIGN.md §5): off-TPU the
+    default runs the fused jnp oracle, and ``REPRO_FORCE_PALLAS_INTERPRET``
+    forces the Pallas interpreter — both must agree with the plain unfused
+    stepper, for the forward step AND the sign=-1 reverse reconstruction."""
     from repro.core.solvers import (RevHeunState, reversible_heun_reverse_step,
                                     reversible_heun_step)
 
@@ -383,13 +383,18 @@ def test_fused_step_dispatch_interpret_matches_oracle(key):
     dw = 0.1 * jax.random.normal(k2, (4, 8))
 
     variants = {}
-    for name, kw in (("unfused", dict(use_pallas=False)),
-                     ("oracle", dict(use_pallas=True)),          # auto: off-TPU
-                     ("interpret", dict(use_pallas=True, interpret=True))):
+    for name, use_pallas, force in (("unfused", False, False),
+                                    ("oracle", True, False),   # auto: off-TPU
+                                    ("interpret", True, True)):
+        if force:
+            monkeypatch.setenv("REPRO_FORCE_PALLAS_INTERPRET", "1")
+        else:
+            monkeypatch.delenv("REPRO_FORCE_PALLAS_INTERPRET", raising=False)
         fwd = reversible_heun_step(state, 0.0, 0.125, dw, drift, diffusion,
-                                   p, "diagonal", **kw)
+                                   p, "diagonal", use_pallas=use_pallas)
         rev = reversible_heun_reverse_step(fwd, 0.125, 0.125, dw, drift,
-                                           diffusion, p, "diagonal", **kw)
+                                           diffusion, p, "diagonal",
+                                           use_pallas=use_pallas)
         variants[name] = (fwd, rev)
     for name in ("oracle", "interpret"):
         for got, want in zip(jax.tree.leaves(variants[name]),
